@@ -6,8 +6,8 @@ selection seam (``@BTL.register`` / ``@PML.register`` /
 to keep alive — but a tier without a registered prober is invisible
 to it: the ledger can quarantine it on in-band failures yet nothing
 ever re-probes it back to HEALTHY, so one wedge silently downgrades
-the job for its remaining lifetime (the exact BENCH_r03-r05 failure
-the health subsystem exists to end).
+the job for its remaining lifetime (the failure the health subsystem
+exists to end).
 
 Evidence that satisfies the rule, anywhere in the file: a call named
 ``register_probe`` / ``register_health_probe`` /

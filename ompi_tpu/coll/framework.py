@@ -128,10 +128,6 @@ def compile_plan(
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from ..core import jax_compat
-
-    jax_compat.ensure()
-
     mesh = comm.mesh
 
     def wrapped(block):

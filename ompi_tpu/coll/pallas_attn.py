@@ -31,7 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_ring import _interpret, _sublane
+from .pallas_ring import (_interpret, _params, _ring_peers, _sublane,
+                          entry_barrier)
 
 _NEG = -1e30
 
@@ -44,6 +45,8 @@ def _ring_attn_kernel(axis_name: str, n: int, causal: bool, scale: float,
     me = jax.lax.axis_index(axis_name)
     right = jax.lax.rem(me + 1, n)
     left = jax.lax.rem(me - 1 + n, n)
+    if n > 1:
+        entry_barrier(_ring_peers(me, n))
 
     # Seed slot 0 with the local KV block (K stacked over V).
     kv_buf[0, :tq] = k_ref[:]
@@ -160,13 +163,8 @@ def ring_attention_block(q: jax.Array, k: jax.Array, v: jax.Array,
     scale = 1.0 / float(dh) ** 0.5
     kernel = functools.partial(_ring_attn_kernel, axis_name, n,
                                bool(causal), scale, h, t)
-    if n == 1:
-        # no remote traffic: collective_id (the cross-device barrier)
-        # must be absent on a 1-member ring
-        params = pltpu.CompilerParams(has_side_effects=True)
-    else:
-        params = pltpu.CompilerParams(has_side_effects=True,
-                                      collective_id=12)
+    # a 1-member ring has no remote peer and so no entry barrier
+    params = _params(None if n == 1 else "attn")
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((t, h, dh), q.dtype,

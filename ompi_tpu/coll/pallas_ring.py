@@ -10,12 +10,26 @@ ICI) and fuses the per-step reduction on the VPU while the next block is
 in flight — the compute/communication overlap the segmented-ring
 algorithm (coll_base_allreduce.c:618) approximates in software.
 
+Entry barrier: every kernel that talks to other devices first meets
+its peers on the barrier semaphore (``pltpu.get_barrier_semaphore``,
+one ``collective_id`` per kernel in ``COLLECTIVE_IDS``). Until a peer
+has entered the kernel its VMEM scratch and semaphores may still belong
+to the previous program, so no remote DMA or signal may reach it
+earlier.
+
 Flow control: the two-slot communication buffer is protected by a
 capacity semaphore the consumer remote-signals back to its upstream
 neighbor after draining a slot; the producer waits before re-filling.
 (The reference's analog is the BTL flow-control window / fastbox
 `in_use` flags, btl_sm_fbox.h:22-60 — without it a fast sender clobbers
-a slot two steps ahead, which we observed in practice.)
+a slot two steps ahead.)
+
+Layout: every VMEM buffer is ``(..., rows, 128)`` with ``rows`` a
+multiple of the dtype's sublane tile, so a dynamic index on a leading
+axis selects whole (8, 128) tiles — Mosaic refuses a dynamic sublane
+offset inside a tile. Payloads larger than the VMEM budget run the
+kernel once per row segment (``_by_segments``); the chunked kernel
+streams HBM->VMEM inside one kernel instead.
 
 These kernels are selected by the `coll/pallas` component (opt-in via
 ``coll_select=pallas`` or per-op tuned rules); `coll/xla` remains the
@@ -29,11 +43,10 @@ algorithms over loopback (SURVEY §4).
 from __future__ import annotations
 
 import functools
-from typing import Any
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -42,10 +55,10 @@ from ..ops import lookup as op_lookup
 from ..ops.op import Op
 
 __all__ = [
-    "ring_allgather", "ring_reduce_scatter", "ring_allreduce",
-    "ring_allreduce_bidir", "ring_allreduce_chunked", "ring_allreduce_rd",
-    "tree_bcast", "tree_reduce", "linear_gather", "linear_scatter",
-    "ppermute_shift",
+    "COLLECTIVE_IDS", "ring_allgather", "ring_reduce_scatter",
+    "ring_allreduce", "ring_allreduce_bidir", "ring_allreduce_chunked",
+    "ring_allreduce_rd", "tree_bcast", "tree_reduce", "linear_gather",
+    "linear_scatter", "ppermute_shift",
 ]
 
 _interpret_var = config.register(
@@ -81,14 +94,23 @@ _rd_cutoff_var = config.register(
                 "coll_tuned_decision_fixed.c:53)",
 )
 
+#: One barrier-semaphore id per kernel: two kernels that shared an id
+#: could satisfy each other's entry barrier.
+COLLECTIVE_IDS = {
+    "allgather": 0, "reduce_scatter": 1, "allreduce": 2, "shift": 3,
+    "alltoall": 4, "bcast": 5, "bidir": 6, "chunked": 7, "rd": 8,
+    "reduce": 9, "gather": 10, "scatter": 11,
+    # sched/pallas_lower table programs, one id per program op
+    "sched_allreduce": 12, "sched_reduce_scatter": 13,
+    "sched_allgather": 14, "sched_window": 15,
+    "quant": 16, "attn": 17,
+}
 
-def interpret_available() -> bool:
-    """Does this jax build ship Mosaic's TPU interpret mode (the
-    inter-device DMA + remote semaphore emulation)? 0.4.x builds do
-    not — there the pallas kernels only run on real TPU hardware, and
-    CPU-tier validation falls back to the sched compiler's table
-    simulator (sched/pallas_lower.simulate)."""
-    return hasattr(pltpu, "InterpretParams")
+#: VMEM bytes a whole-payload kernel call may hold (v5e has 16 MiB of
+#: scoped VMEM; the rest is headroom for Mosaic's own temporaries).
+_VMEM_BUDGET = 8 << 20
+
+_LOGICAL = pltpu.DeviceIdType.LOGICAL
 
 
 def _interpret():
@@ -96,16 +118,37 @@ def _interpret():
     the mode that emulates inter-device DMA + remote semaphore signals
     (plain ``interpret=True`` cannot discharge remote signals)."""
     forced = _interpret_var.value
-    if forced is not None and not forced:
-        return False
-    if forced or jax.default_backend() == "cpu":
-        if not interpret_available():
-            raise RuntimeError(
-                "this jax build has no Mosaic TPU interpret mode "
-                "(pltpu.InterpretParams); pallas kernels need a TPU "
-                "backend or jax >= 0.5")
-        return pltpu.InterpretParams()
-    return False
+    if forced is None:
+        forced = jax.default_backend() == "cpu"
+    return pltpu.InterpretParams() if forced else False
+
+
+def _params(kernel: str | None) -> pltpu.CompilerParams:
+    """Compiler params; ``kernel`` names the barrier-semaphore id of a
+    kernel that meets its peers at entry (None: no remote peers)."""
+    if kernel is None:
+        return pltpu.CompilerParams(has_side_effects=True)
+    return pltpu.CompilerParams(has_side_effects=True,
+                                collective_id=COLLECTIVE_IDS[kernel])
+
+
+def entry_barrier(peers) -> None:
+    """Signal every peer on the barrier semaphore and wait until as
+    many signals arrived: afterwards each listed peer has entered this
+    kernel. The peer relation must be symmetric (each of my peers lists
+    me as often as I list it)."""
+    sem = pltpu.get_barrier_semaphore()
+    for p in peers:
+        pltpu.semaphore_signal(sem, 1, device_id=p, device_id_type=_LOGICAL)
+    pltpu.semaphore_wait(sem, len(peers))
+
+
+def _ring_peers(me, n):
+    return [jax.lax.rem(me + 1, n), jax.lax.rem(me - 1 + n, n)]
+
+
+def _all_peers(me, n):
+    return [jax.lax.rem(me + k, n) for k in range(1, n)]
 
 
 def _combine_blocks(op: Op, a, b):
@@ -114,9 +157,74 @@ def _combine_blocks(op: Op, a, b):
     return op.combine(a, b)
 
 
+def _sublane(dtype) -> int:
+    """Minimum second-to-last-dim tile for the dtype (pallas_guide:
+    (8,128) f32, (16,128) bf16, (32,128) int8)."""
+    return max(8, 32 // max(1, jnp.dtype(dtype).itemsize))
+
+
+def _plan_rows(lanes: int, dtype, width: int, quantum: int = 0
+               ) -> tuple[int, int]:
+    """(rows, seg_rows) for blocks of ``lanes`` elements laid out as
+    (rows, 128): ``rows`` is a multiple of the sublane tile (or of
+    ``quantum``) and of ``seg_rows``, the rows one kernel call takes so
+    that ``width`` block-sized VMEM buffers fit the budget."""
+    a = quantum or _sublane(dtype)
+    rows = -(-max(lanes, 1) // 128)
+    rows = -(-rows // a) * a
+    row_bytes = width * 128 * jnp.dtype(dtype).itemsize
+    seg = min(rows, max(a, _VMEM_BUDGET // row_bytes // a * a))
+    return -(-rows // seg) * seg, seg
+
+
+def _tile(flat: jax.Array, rows: int) -> jax.Array:
+    """(m, lanes) -> (m, rows, 128), zero-padded."""
+    pad = rows * 128 - flat.shape[1]
+    if pad:
+        flat = jnp.pad(flat, ((0, 0), (0, pad)))
+    return flat.reshape(flat.shape[0], rows, 128)
+
+
+def _untile(t: jax.Array, lanes: int) -> jax.Array:
+    """(m, rows, 128) -> (m, lanes)."""
+    return t.reshape(t.shape[0], -1)[:, :lanes]
+
+
+def _by_segments(call: Callable, x: jax.Array, seg_rows: int
+                 ) -> jax.Array:
+    """Apply ``call`` to each ``seg_rows`` slice of the row axis (-2)
+    of ``x`` and of its result — one kernel call per segment, each with
+    its own entry barrier. Every kernel here works row-wise, so the
+    segments are independent."""
+    rows = x.shape[-2]
+    if rows <= seg_rows:
+        return call(x)
+    nseg = rows // seg_rows
+    xs = jnp.moveaxis(
+        x.reshape(x.shape[:-2] + (nseg, seg_rows, 128)), -3, 0)
+    ys = jax.lax.map(call, xs)
+    ys = jnp.moveaxis(ys, 0, -3)
+    return ys.reshape(ys.shape[:-3] + (rows, 128))
+
+
+def _vmem_call(kernel, out_shape: tuple, dtype, axis_name: str,
+               scratch_shapes: list, cid: str | None, n_in: int = 1):
+    """pallas_call with whole-array VMEM operands."""
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(out_shape, dtype,
+                                       vma=frozenset({axis_name})),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * n_in,
+        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        scratch_shapes=scratch_shapes,
+        compiler_params=_params(cid),
+        interpret=_interpret(),
+    )
+
+
 # ---------------------------------------------------------------------------
-# Kernels. All operate on a (n, chunk) view: the leading axis indexes
-# ring positions (rank blocks), `chunk` is the flattened payload slice.
+# Kernels. Block refs are (rows, 128); the leading axis of an (n, rows,
+# 128) ref indexes ring positions (rank blocks).
 # ---------------------------------------------------------------------------
 
 def _allgather_kernel(axis_name: str, n: int, local_ref, out_ref,
@@ -124,17 +232,17 @@ def _allgather_kernel(axis_name: str, n: int, local_ref, out_ref,
     me = jax.lax.axis_index(axis_name)
     right = jax.lax.rem(me + 1, n)
     left = jax.lax.rem(me - 1 + n, n)
+    entry_barrier(_ring_peers(me, n))
 
     out_ref[me] = local_ref[:]
     comm_buf[0] = local_ref[:]
     # Post-seed credit: gates the upstream neighbor's step-1 write into
-    # comm_buf[0] so a fast neighbor cannot land it before the seed
-    # (kernel-start skew; there is no implicit entry barrier). A
+    # comm_buf[0] so a fast neighbor cannot land it before the seed. A
     # 2-member ring has no step 1 in this n-1-step schedule — emitting
     # the credit would leave cap_sem[0] non-zero at kernel exit.
     if n > 2:
         pltpu.semaphore_signal(cap_sem.at[0], inc=1, device_id=left,
-                               device_id_type=pltpu.DeviceIdType.LOGICAL)
+                               device_id_type=_LOGICAL)
 
     for step in range(n - 1):
         slot = step % 2
@@ -150,7 +258,7 @@ def _allgather_kernel(axis_name: str, n: int, local_ref, out_ref,
             send_sem=send_sem.at[slot],
             recv_sem=recv_sem.at[nslot],
             device_id=right,
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
+            device_id_type=_LOGICAL,
         )
         rdma.start()
         rdma.wait()
@@ -158,10 +266,8 @@ def _allgather_kernel(axis_name: str, n: int, local_ref, out_ref,
         out_ref[src_block] = comm_buf[nslot]
         # Drained comm_buf[nslot]; let upstream reuse it at step+2.
         if step < n - 3:
-            pltpu.semaphore_signal(
-                cap_sem.at[nslot], inc=1, device_id=left,
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
-            )
+            pltpu.semaphore_signal(cap_sem.at[nslot], inc=1,
+                                   device_id=left, device_id_type=_LOGICAL)
 
 
 def _reduce_scatter_kernel(axis_name: str, n: int, op: Op, x_ref, out_ref,
@@ -173,17 +279,17 @@ def _reduce_scatter_kernel(axis_name: str, n: int, op: Op, x_ref, out_ref,
     me = jax.lax.axis_index(axis_name)
     right = jax.lax.rem(me + 1, n)
     left = jax.lax.rem(me - 1 + n, n)
+    entry_barrier(_ring_peers(me, n))
 
-    # Start the chain with our partial for the block owned by our left
-    # neighbor's ... standard schedule: send block (me - 1), so that
-    # block b circulates from rank b+1 around to rank b, accumulating.
+    # Send block (me - 1) first, so that block b circulates from rank
+    # b+1 around to rank b, accumulating.
     first = jax.lax.rem(me - 1 + n, n)
     comm_buf[0] = x_ref[first]
     # Post-seed credit gating the upstream step-1 write (see allgather;
     # same n==2 exclusion — the n-1-step schedule has no step 1 there).
     if n > 2:
         pltpu.semaphore_signal(cap_sem.at[0], inc=1, device_id=left,
-                               device_id_type=pltpu.DeviceIdType.LOGICAL)
+                               device_id_type=_LOGICAL)
 
     for step in range(n - 1):
         slot = step % 2
@@ -196,22 +302,20 @@ def _reduce_scatter_kernel(axis_name: str, n: int, op: Op, x_ref, out_ref,
             send_sem=send_sem.at[slot],
             recv_sem=recv_sem.at[nslot],
             device_id=right,
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
+            device_id_type=_LOGICAL,
         )
         rdma.start()
         rdma.wait()
-        # Arrived: partial sum for block (me - step - 2) ... derive from
-        # schedule: we received what left sent = left's block index
-        # (left - step - 1) = me - step - 2.
+        # Arrived: left's partial for block (left - step - 1), i.e.
+        # block (me - step - 2).
         blk = jax.lax.rem(me - step - 2 + 2 * n, n)
         reduced = _combine_blocks(op, comm_buf[nslot], x_ref[blk])
         if step < n - 2:
             comm_buf[nslot] = reduced
             if step < n - 3:
-                pltpu.semaphore_signal(
-                    cap_sem.at[nslot], inc=1, device_id=left,
-                    device_id_type=pltpu.DeviceIdType.LOGICAL,
-                )
+                pltpu.semaphore_signal(cap_sem.at[nslot], inc=1,
+                                       device_id=left,
+                                       device_id_type=_LOGICAL)
         else:
             out_ref[:] = reduced
 
@@ -225,12 +329,13 @@ def _allreduce_kernel(axis_name: str, n: int, op: Op, x_ref, out_ref,
     me = jax.lax.axis_index(axis_name)
     right = jax.lax.rem(me + 1, n)
     left = jax.lax.rem(me - 1 + n, n)
+    entry_barrier(_ring_peers(me, n))
 
     first = jax.lax.rem(me - 1 + n, n)
     comm_buf[0] = x_ref[first]
     # Post-seed credit gating the upstream step-1 write (see allgather).
     pltpu.semaphore_signal(cap_sem.at[0], inc=1, device_id=left,
-                           device_id_type=pltpu.DeviceIdType.LOGICAL)
+                           device_id_type=_LOGICAL)
 
     for step in range(2 * (n - 1)):
         slot = step % 2
@@ -243,7 +348,7 @@ def _allreduce_kernel(axis_name: str, n: int, op: Op, x_ref, out_ref,
             send_sem=send_sem.at[slot],
             recv_sem=recv_sem.at[nslot],
             device_id=right,
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
+            device_id_type=_LOGICAL,
         )
         rdma.start()
         rdma.wait()
@@ -261,10 +366,8 @@ def _allreduce_kernel(axis_name: str, n: int, op: Op, x_ref, out_ref,
             blk = jax.lax.rem(me - (step - (n - 1)) - 1 + 2 * n, n)
             out_ref[blk] = comm_buf[nslot]
         if step < 2 * (n - 1) - 2:
-            pltpu.semaphore_signal(
-                cap_sem.at[nslot], inc=1, device_id=left,
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
-            )
+            pltpu.semaphore_signal(cap_sem.at[nslot], inc=1,
+                                   device_id=left, device_id_type=_LOGICAL)
 
 
 # ---------------------------------------------------------------------------
@@ -273,10 +376,7 @@ def _allreduce_kernel(axis_name: str, n: int, op: Op, x_ref, out_ref,
 # bounded buffer) re-built for the TPU memory hierarchy. The payload
 # stays in HBM; the VMEM working set is six segment-sized slots (input
 # prefetch x2, comm buffer x2, output stage x2), so shard sizes are
-# bounded by HBM, not the ~16 MiB VMEM. Data is laid out (n, rows, 128)
-# so every slot slice is a cleanly tiled 2-D block (Mosaic rejects
-# dim-0 slices of 2-D buffers that break the (8,128) tiling — found by
-# compiling on hardware).
+# bounded by HBM, not VMEM.
 #
 # Flow control has two levels: within a segment, the capacity semaphore
 # of the plain ring kernels; across segments, a credit semaphore — a
@@ -287,12 +387,6 @@ def _allreduce_kernel(axis_name: str, n: int, op: Op, x_ref, out_ref,
 # every segment's wait is unconditional (no predicated semaphore ops).
 # ---------------------------------------------------------------------------
 
-def _sublane(dtype) -> int:
-    """Minimum second-to-last-dim tile for the dtype (pallas_guide:
-    (8,128) f32, (16,128) bf16, (32,128) int8)."""
-    return max(8, 32 // max(1, jnp.dtype(dtype).itemsize))
-
-
 def _allreduce_chunked_kernel(axis_name: str, n: int, op: Op, seg: int,
                               n_segs: int, x_hbm, out_hbm,
                               comm_buf, x_buf, out_buf,
@@ -301,10 +395,11 @@ def _allreduce_chunked_kernel(axis_name: str, n: int, op: Op, seg: int,
     me = jax.lax.axis_index(axis_name)
     right = jax.lax.rem(me + 1, n)
     left = jax.lax.rem(me - 1 + n, n)
+    entry_barrier(_ring_peers(me, n))
 
     # Prime one segment credit so every segment (incl. 0) waits uniformly.
     pltpu.semaphore_signal(seg_sem, inc=1, device_id=left,
-                           device_id_type=pltpu.DeviceIdType.LOGICAL)
+                           device_id_type=_LOGICAL)
 
     def seg_body(si, _):
         off = si * seg
@@ -338,7 +433,7 @@ def _allreduce_chunked_kernel(axis_name: str, n: int, op: Op, seg: int,
         # the delivered partial (the recv semaphore count would still
         # satisfy our step-1 wait). Gate every step-1 send on it.
         pltpu.semaphore_signal(cap_sem.at[0], inc=1, device_id=left,
-                               device_id_type=pltpu.DeviceIdType.LOGICAL)
+                               device_id_type=_LOGICAL)
 
         writes = []  # in-flight VMEM->HBM output copies (unrolled)
         for step in range(2 * (n - 1)):
@@ -352,7 +447,7 @@ def _allreduce_chunked_kernel(axis_name: str, n: int, op: Op, seg: int,
                 send_sem=send_sem.at[slot],
                 recv_sem=recv_sem.at[nslot],
                 device_id=right,
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
+                device_id_type=_LOGICAL,
             )
             rdma.start()
             # Prefetch the input block for the NEXT reduce-scatter step
@@ -385,13 +480,12 @@ def _allreduce_chunked_kernel(axis_name: str, n: int, op: Op, seg: int,
                 writes.append(out_dma(blk, wslot))
                 writes[-1].start()
             if step < 2 * (n - 1) - 2:
-                pltpu.semaphore_signal(
-                    cap_sem.at[nslot], inc=1, device_id=left,
-                    device_id_type=pltpu.DeviceIdType.LOGICAL,
-                )
+                pltpu.semaphore_signal(cap_sem.at[nslot], inc=1,
+                                       device_id=left,
+                                       device_id_type=_LOGICAL)
         # Drained every send from the left neighbor: grant next credit.
         pltpu.semaphore_signal(seg_sem, inc=1, device_id=left,
-                               device_id_type=pltpu.DeviceIdType.LOGICAL)
+                               device_id_type=_LOGICAL)
         # Out-copies must land before their slots are reused next segment.
         for w in writes[-2:]:
             w.wait()
@@ -408,17 +502,16 @@ def _selfdma_chunked_kernel(axis_name: str, seg: int, n_segs: int,
                             out_sem):
     """Degenerate 1-member ring of the chunked schedule: per segment,
     HBM->VMEM prefetch, one self-targeted remote DMA (the ICI machinery
-    with device_id == me), VMEM->HBM writeback — double-buffered. This
-    is the bench's on-chip Mosaic proof path: a 1-rank allreduce is the
-    identity, but every DMA engine the n>1 schedule uses runs for real.
+    with device_id == me), VMEM->HBM writeback — double-buffered. A
+    1-rank allreduce is the identity, but every DMA engine the n>1
+    schedule uses runs for real: the one-chip proof that the kernel
+    path compiles and executes.
 
     3-stage software pipeline: the remote DMA of segment si is waited
     only at iteration si+1, so IN(si+1), RDMA(si) and OUT(si-1) are all
-    in flight together (a back-to-back start/wait serialized the three
-    engines and capped the measured HBM rate at ~half the roofline).
-    Slot hazards: RDMA(si) needs comm_buf[si%2] free -> OUT(si-2)
-    waited; IN(si+1) needs x_buf[(si+1)%2] free -> RDMA(si-1) waited;
-    OUT(si) needs RDMA(si) waited."""
+    in flight together. Slot hazards: RDMA(si) needs comm_buf[si%2]
+    free -> OUT(si-2) waited; IN(si+1) needs x_buf[(si+1)%2] free ->
+    RDMA(si-1) waited; OUT(si) needs RDMA(si) waited."""
     def in_dma(si):
         return pltpu.make_async_copy(
             x_hbm.at[0, pl.ds(si * seg, seg)], x_buf.at[si % 2],
@@ -435,7 +528,7 @@ def _selfdma_chunked_kernel(axis_name: str, seg: int, n_segs: int,
             src_ref=x_buf.at[slot], dst_ref=comm_buf.at[slot],
             send_sem=send_sem.at[slot], recv_sem=recv_sem.at[slot],
             device_id=jax.lax.axis_index(axis_name),
-            device_id_type=pltpu.DeviceIdType.LOGICAL)
+            device_id_type=_LOGICAL)
 
     in_dma(0).start()
     if n_segs > 1:
@@ -461,8 +554,8 @@ def ring_allreduce_chunked(x: jax.Array, axis_name: str, op: Any = "sum",
     """Inside shard_map: this rank's full contribution (any shape) ->
     fully reduced buffer of the same shape, streamed through VMEM in
     double-buffered segments. Unlike the whole-payload kernels, handles
-    shards far larger than VMEM (the reference's segmented ring regime,
-    coll_base_allreduce.c:618)."""
+    shards far larger than VMEM in one kernel (the reference's
+    segmented ring regime, coll_base_allreduce.c:618)."""
     op = op_lookup(op)
     n = jax.lax.axis_size(axis_name)
     if seg_bytes is None:
@@ -484,36 +577,20 @@ def ring_allreduce_chunked(x: jax.Array, axis_name: str, op: Any = "sum",
     if pad:
         flat = jnp.pad(flat, (0, pad))
     blocks = flat.reshape(n, rows, 128)
+    slots = [pltpu.VMEM((2, seg_rows, 128), flat.dtype)] * 3
+    dma = [pltpu.SemaphoreType.DMA((2,))] * 4
 
     if n == 1:
         kernel = functools.partial(_selfdma_chunked_kernel, axis_name,
                                    seg_rows, n_segs)
-        scratch = [
-            pltpu.VMEM((2, seg_rows, 128), flat.dtype),
-            pltpu.VMEM((2, seg_rows, 128), flat.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ]
-        # collective_id must be absent on a 1-member ring (no barrier).
-        params = pltpu.CompilerParams(has_side_effects=True)
+        scratch = slots[:2] + dma
+        cid = None  # no remote peer: no entry barrier
     else:
         kernel = functools.partial(_allreduce_chunked_kernel, axis_name,
                                    n, op, seg_rows, n_segs)
-        scratch = [
-            pltpu.VMEM((2, seg_rows, 128), flat.dtype),
-            pltpu.VMEM((2, seg_rows, 128), flat.dtype),
-            pltpu.VMEM((2, seg_rows, 128), flat.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.REGULAR((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.REGULAR,
-        ]
-        params = pltpu.CompilerParams(has_side_effects=True,
-                                      collective_id=7)
+        scratch = (slots + dma[:2] + [pltpu.SemaphoreType.REGULAR((2,))]
+                   + dma[2:] + [pltpu.SemaphoreType.REGULAR])
+        cid = "chunked"
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((n, rows, 128), flat.dtype,
@@ -521,7 +598,7 @@ def ring_allreduce_chunked(x: jax.Array, axis_name: str, op: Any = "sum",
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=scratch,
-        compiler_params=params,
+        compiler_params=_params(cid),
         interpret=_interpret(),
     )(blocks)
     flat_out = out.reshape(-1)
@@ -531,11 +608,10 @@ def ring_allreduce_chunked(x: jax.Array, axis_name: str, op: Any = "sum",
 
 
 # ---------------------------------------------------------------------------
-# Host-callable wrappers (shard_map bodies). Input per shard: the local
-# (n, chunk) contribution view.
+# Host-callable wrappers (shard_map bodies).
 # ---------------------------------------------------------------------------
 
-def _sems():
+def _ring_sems():
     return [
         pltpu.SemaphoreType.DMA((2,)),
         pltpu.SemaphoreType.DMA((2,)),
@@ -543,39 +619,29 @@ def _sems():
     ]
 
 
-def _pad_chunk(x: jax.Array) -> tuple[jax.Array, int, tuple]:
-    """Flatten to (lanes,) padded to the f32 tile quantum so VMEM
-    blocks tile cleanly (pallas_guide: min tile (8,128) for f32)."""
-    orig_shape = x.shape
-    flat = x.reshape(-1)
-    pad = (-flat.size) % 128
-    if pad:
-        flat = jnp.pad(flat, (0, pad))
-    return flat, pad, orig_shape
-
-
 def ring_allgather(x: jax.Array, axis_name: str) -> jax.Array:
     """Inside shard_map: local block (chunk,) -> gathered (n, chunk)."""
     n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x[None]
-    flat, pad, shape = _pad_chunk(x)
+    shape = x.shape
+    flat = x.reshape(1, -1)
+    lanes = flat.shape[1]
+    rows, seg = _plan_rows(lanes, flat.dtype, n + 3)
     kernel = functools.partial(_allgather_kernel, axis_name, n)
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((n, flat.size), flat.dtype,
-                                       vma=frozenset({axis_name})),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        scratch_shapes=[pltpu.VMEM((2, flat.size), flat.dtype)] + _sems(),
-        compiler_params=pltpu.CompilerParams(
-            has_side_effects=True, collective_id=0,
-        ),
-        interpret=_interpret(),
-    )(flat)
-    if pad:
-        out = out[:, :-pad]
-    return out.reshape((n,) + shape)
+
+    def call(b):
+        r = b.shape[-2]
+        return _vmem_call(
+            kernel, (n, r, 128), b.dtype, axis_name,
+            scratch_shapes=[pltpu.VMEM((2, r, 128), b.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SemaphoreType.REGULAR((2,))],
+            cid="allgather")(b)
+
+    out = _by_segments(call, _tile(flat, rows)[0], seg)
+    return _untile(out, lanes).reshape((n,) + shape)
 
 
 def ring_reduce_scatter(x: jax.Array, axis_name: str, op: Any = "sum"
@@ -589,25 +655,18 @@ def ring_reduce_scatter(x: jax.Array, axis_name: str, op: Any = "sum"
     shape = x.shape[1:]
     flat = x.reshape(n, -1)
     lanes = flat.shape[1]
-    pad = (-lanes) % 128
-    if pad:
-        flat = jnp.pad(flat, ((0, 0), (0, pad)))
+    rows, seg = _plan_rows(lanes, flat.dtype, n + 3)
     kernel = functools.partial(_reduce_scatter_kernel, axis_name, n, op)
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((flat.shape[1],), flat.dtype,
-                                       vma=frozenset({axis_name})),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        scratch_shapes=[pltpu.VMEM((2, flat.shape[1]), flat.dtype)] + _sems(),
-        compiler_params=pltpu.CompilerParams(
-            has_side_effects=True, collective_id=1,
-        ),
-        interpret=_interpret(),
-    )(flat)
-    if pad:
-        out = out[:-pad]
-    return out.reshape(shape)
+
+    def call(b):
+        r = b.shape[-2]
+        return _vmem_call(
+            kernel, (r, 128), b.dtype, axis_name,
+            [pltpu.VMEM((2, r, 128), b.dtype)] + _ring_sems(),
+            "reduce_scatter")(b)
+
+    out = _by_segments(call, _tile(flat, rows), seg)
+    return _untile(out[None], lanes)[0].reshape(shape)
 
 
 def ring_allreduce(x: jax.Array, axis_name: str, op: Any = "sum"
@@ -621,57 +680,53 @@ def ring_allreduce(x: jax.Array, axis_name: str, op: Any = "sum"
         return x
     shape = x.shape[1:]
     flat = x.reshape(n, -1)
-    pad = (-flat.shape[1]) % 128
-    if pad:
-        flat = jnp.pad(flat, ((0, 0), (0, pad)))
+    lanes = flat.shape[1]
+    rows, seg = _plan_rows(lanes, flat.dtype, 2 * n + 2)
     kernel = functools.partial(_allreduce_kernel, axis_name, n, op)
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(flat.shape, flat.dtype,
-                                       vma=frozenset({axis_name})),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        scratch_shapes=[pltpu.VMEM((2, flat.shape[1]), flat.dtype)] + _sems(),
-        compiler_params=pltpu.CompilerParams(
-            has_side_effects=True, collective_id=2,
-        ),
-        interpret=_interpret(),
-    )(flat)
-    if pad:
-        out = out[:, :-pad]
-    return out.reshape((n,) + shape)
+
+    def call(b):
+        r = b.shape[-2]
+        return _vmem_call(
+            kernel, b.shape, b.dtype, axis_name,
+            [pltpu.VMEM((2, r, 128), b.dtype)] + _ring_sems(),
+            "allreduce")(b)
+
+    out = _by_segments(call, _tile(flat, rows), seg)
+    return _untile(out, lanes).reshape((n,) + shape)
 
 
-def _allreduce_bidir_kernel(axis_name: str, n: int, op: Op, half: int,
+def _allreduce_bidir_kernel(axis_name: str, n: int, op: Op,
                             x_ref, out_ref, buf_a, buf_b,
                             ssem_a, rsem_a, csem_a,
                             ssem_b, rsem_b, csem_b):
-    """Bidirectional ring allreduce: the payload splits in half and the
-    two halves run the 2(n-1)-step ring schedule in OPPOSITE directions
-    simultaneously, so both ICI directions of the torus link carry data
-    every step — 2x the link bandwidth of the unidirectional ring
-    (reference's algorithm space has only the one-direction ring,
-    coll_base_allreduce.c:341; this is the TPU-topology upgrade).
-    Both directions' DMAs are started before either is awaited."""
+    """Bidirectional ring allreduce: the payload splits in half (axis 1
+    of the (n, 2, rows, 128) refs) and the two halves run the
+    2(n-1)-step ring schedule in OPPOSITE directions simultaneously, so
+    both ICI directions of the torus link carry data every step — 2x
+    the link bandwidth of the unidirectional ring (reference's
+    algorithm space has only the one-direction ring,
+    coll_base_allreduce.c:341; this is the TPU-topology upgrade). Both
+    directions' DMAs are started before either is awaited."""
     me = jax.lax.axis_index(axis_name)
+    entry_barrier(_ring_peers(me, n))
     parts = (
-        (1, buf_a, ssem_a, rsem_a, csem_a, slice(0, half)),
-        (-1, buf_b, ssem_b, rsem_b, csem_b, slice(half, None)),
+        (1, buf_a, ssem_a, rsem_a, csem_a, 0),
+        (-1, buf_b, ssem_b, rsem_b, csem_b, 1),
     )
-    for d, buf, _ss, _rs, csem, sl in parts:
+    for d, buf, _ss, _rs, csem, h in parts:
         first = jax.lax.rem(me - d + n, n)
-        buf[0] = x_ref[first, sl]
+        buf[0] = x_ref[first, h]
         # Post-seed credit to this direction's upstream (see allgather).
         pltpu.semaphore_signal(
             csem.at[0], inc=1, device_id=jax.lax.rem(me - d + n, n),
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
+            device_id_type=_LOGICAL,
         )
 
     for step in range(2 * (n - 1)):
         slot = step % 2
         nslot = (step + 1) % 2
         descs = []
-        for d, buf, ssem, rsem, csem, sl in parts:
+        for d, buf, ssem, rsem, csem, h in parts:
             if step >= 1:
                 pltpu.semaphore_wait(csem.at[nslot], 1)
             rdma = pltpu.make_async_remote_copy(
@@ -680,28 +735,28 @@ def _allreduce_bidir_kernel(axis_name: str, n: int, op: Op, half: int,
                 send_sem=ssem.at[slot],
                 recv_sem=rsem.at[nslot],
                 device_id=jax.lax.rem(me + d + n, n),
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
+                device_id_type=_LOGICAL,
             )
             rdma.start()  # both directions in flight together
             descs.append(rdma)
-        for (d, buf, ssem, rsem, csem, sl), rdma in zip(parts, descs):
+        for (d, buf, ssem, rsem, csem, h), rdma in zip(parts, descs):
             rdma.wait()
             if step < n - 1:
                 blk = jax.lax.rem(me - d * (step + 2) + 3 * n, n)
-                val = _combine_blocks(op, buf[nslot], x_ref[blk, sl])
+                val = _combine_blocks(op, buf[nslot], x_ref[blk, h])
                 buf[nslot] = val
                 if step == n - 2:
-                    out_ref[blk, sl] = val  # blk == me: first done block
+                    out_ref[blk, h] = val  # blk == me: first done block
             else:
                 blk = jax.lax.rem(
                     me - d * (step - (n - 1) + 1) + 3 * n, n
                 )
-                out_ref[blk, sl] = buf[nslot]
+                out_ref[blk, h] = buf[nslot]
             if step < 2 * (n - 1) - 2:
                 pltpu.semaphore_signal(
                     csem.at[nslot], inc=1,
                     device_id=jax.lax.rem(me - d + n, n),
-                    device_id_type=pltpu.DeviceIdType.LOGICAL,
+                    device_id_type=_LOGICAL,
                 )
 
 
@@ -717,6 +772,7 @@ def _allreduce_rd_kernel(axis_name: str, n: int, op: Op,
     ahead — per-round semaphores keep its early DMA from satisfying an
     earlier round's wait (slot-mod-2 sharing would)."""
     me = jax.lax.axis_index(axis_name)
+    entry_barrier(_all_peers(me, n))
     out_ref[:] = x_ref[:]
     rounds = (n - 1).bit_length()
     for k in range(rounds):
@@ -728,7 +784,7 @@ def _allreduce_rd_kernel(axis_name: str, n: int, op: Op,
             send_sem=send_sems.at[k],
             recv_sem=recv_sems.at[k],
             device_id=partner,
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
+            device_id_type=_LOGICAL,
         )
         rdma.start()
         rdma.wait()
@@ -744,6 +800,31 @@ def _allreduce_rd_kernel(axis_name: str, n: int, op: Op,
             out_ref[:] = _combine_blocks(op, out_ref[:], comm_buf[k])
 
 
+def _flat_call(x: jax.Array, axis_name: str, kernel, width: int,
+               scratch: Callable, cid: str) -> jax.Array:
+    """Whole-payload kernel over one (rows, 128) block in and out:
+    ``scratch(rows, dtype)`` builds the per-call scratch list."""
+    shape = x.shape
+    flat = x.reshape(1, -1)
+    lanes = flat.shape[1]
+    rows, seg = _plan_rows(lanes, flat.dtype, width)
+
+    def call(b):
+        return _vmem_call(kernel, b.shape, b.dtype, axis_name,
+                          scratch(b.shape[-2], b.dtype), cid)(b)
+
+    out = _by_segments(call, _tile(flat, rows)[0], seg)
+    return _untile(out[None], lanes)[0].reshape(shape)
+
+
+def _round_scratch(rounds: int):
+    def scratch(r, dtype):
+        return [pltpu.VMEM((rounds, r, 128), dtype),
+                pltpu.SemaphoreType.DMA((rounds,)),
+                pltpu.SemaphoreType.DMA((rounds,))]
+    return scratch
+
+
 def ring_allreduce_rd(x: jax.Array, axis_name: str, op: Any = "sum"
                       ) -> jax.Array:
     """Inside shard_map: full local contribution -> fully reduced buffer
@@ -757,28 +838,10 @@ def ring_allreduce_rd(x: jax.Array, axis_name: str, op: Any = "sum"
         raise ValueError(
             f"recursive doubling needs a power-of-two ring, got {n}"
         )
-    flat, pad, shape = _pad_chunk(x)
     rounds = (n - 1).bit_length()
     kernel = functools.partial(_allreduce_rd_kernel, axis_name, n, op)
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((flat.size,), flat.dtype,
-                                       vma=frozenset({axis_name})),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((rounds, flat.size), flat.dtype),
-            pltpu.SemaphoreType.DMA((rounds,)),
-            pltpu.SemaphoreType.DMA((rounds,)),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            has_side_effects=True, collective_id=8,
-        ),
-        interpret=_interpret(),
-    )(flat)
-    if pad:
-        out = out[:-pad]
-    return out.reshape(shape)
+    return _flat_call(x, axis_name, kernel, rounds + 2,
+                      _round_scratch(rounds), "rd")
 
 
 def _tree_reduce_kernel(axis_name: str, n: int, root: int, op: Op,
@@ -791,6 +854,7 @@ def _tree_reduce_kernel(axis_name: str, n: int, root: int, op: Op,
     in ascending subtree order. Per-round buffers + semaphores for the
     same skew reason as the rd kernel."""
     me = jax.lax.axis_index(axis_name)
+    entry_barrier(_all_peers(me, n))
     rel = jax.lax.rem(me - root + n, n)
     out_ref[:] = x_ref[:]
     rounds = (n - 1).bit_length()
@@ -806,7 +870,7 @@ def _tree_reduce_kernel(axis_name: str, n: int, root: int, op: Op,
             send_sem=send_sems.at[k],
             recv_sem=recv_sems.at[k],
             device_id=dst,
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
+            device_id_type=_LOGICAL,
         )
 
         @pl.when(is_send)
@@ -831,29 +895,11 @@ def tree_reduce(x: jax.Array, axis_name: str, op: Any = "sum",
     n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
-    flat, pad, shape = _pad_chunk(x)
     rounds = (n - 1).bit_length()
     kernel = functools.partial(_tree_reduce_kernel, axis_name, n,
                                int(root), op)
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((flat.size,), flat.dtype,
-                                       vma=frozenset({axis_name})),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((rounds, flat.size), flat.dtype),
-            pltpu.SemaphoreType.DMA((rounds,)),
-            pltpu.SemaphoreType.DMA((rounds,)),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            has_side_effects=True, collective_id=9,
-        ),
-        interpret=_interpret(),
-    )(flat)
-    if pad:
-        out = out[:-pad]
-    return out.reshape(shape)
+    return _flat_call(x, axis_name, kernel, rounds + 2,
+                      _round_scratch(rounds), "reduce")
 
 
 def _tree_bcast_kernel(axis_name: str, n: int, root: int,
@@ -866,9 +912,9 @@ def _tree_bcast_kernel(axis_name: str, n: int, root: int,
     receivers park on the recv semaphore (wait_recv). The receiver
     remote-signals readiness to its sender BEFORE parking — the DMA
     targets the same out_ref the receiver initializes at kernel start,
-    and with skewed kernel-start times an unsynchronized send could
-    land before that init overwrites it."""
+    so an unsynchronized send could land before that init."""
     me = jax.lax.axis_index(axis_name)
+    entry_barrier(_all_peers(me, n))
     rel = jax.lax.rem(me - root + n, n)
     out_ref[:] = x_ref[:]
     rounds = max(1, (n - 1).bit_length())
@@ -881,7 +927,7 @@ def _tree_bcast_kernel(axis_name: str, n: int, root: int,
             send_sem=send_sem.at[k % 2],
             recv_sem=recv_sem.at[k % 2],
             device_id=dst,
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
+            device_id_type=_LOGICAL,
         )
         is_recv = jnp.logical_and(rel >= bit, rel < 2 * bit)
 
@@ -891,7 +937,7 @@ def _tree_bcast_kernel(axis_name: str, n: int, root: int,
             pltpu.semaphore_signal(
                 ready_sem.at[k % 2], inc=1,
                 device_id=jax.lax.rem(me - bit + n, n),
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
+                device_id_type=_LOGICAL,
             )
 
         @pl.when(jnp.logical_and(rel < bit, rel + bit < n))
@@ -916,38 +962,23 @@ def ring_allreduce_bidir(x: jax.Array, axis_name: str, op: Any = "sum"
         return x
     shape = x.shape[1:]
     flat = x.reshape(n, -1)
-    pad = (-flat.shape[1]) % 256  # two 128-lane-aligned halves
-    if pad:
-        flat = jnp.pad(flat, ((0, 0), (0, pad)))
     lanes = flat.shape[1]
-    half = lanes // 2
-    kernel = functools.partial(
-        _allreduce_bidir_kernel, axis_name, n, op, half
-    )
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(flat.shape, flat.dtype,
-                                       vma=frozenset({axis_name})),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((2, half), flat.dtype),
-            pltpu.VMEM((2, lanes - half), flat.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.REGULAR((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.REGULAR((2,)),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            has_side_effects=True, collective_id=6,
-        ),
-        interpret=_interpret(),
-    )(flat)
-    if pad:
-        out = out[:, :-pad]
-    return out.reshape((n,) + shape)
+    half = -(-lanes // 2)
+    rows, seg = _plan_rows(half, flat.dtype, 4 * n + 4)
+    t = _tile(flat, 2 * rows).reshape(n, 2, rows, 128)
+    kernel = functools.partial(_allreduce_bidir_kernel, axis_name, n, op)
+
+    def call(b):
+        r = b.shape[-2]
+        return _vmem_call(
+            kernel, b.shape, b.dtype, axis_name,
+            [pltpu.VMEM((2, r, 128), b.dtype),
+             pltpu.VMEM((2, r, 128), b.dtype)]
+            + _ring_sems() + _ring_sems(),
+            "bidir")(b)
+
+    out = _by_segments(call, t, seg).reshape(n, 2 * rows, 128)
+    return _untile(out, lanes).reshape((n,) + shape)
 
 
 def tree_bcast(x: jax.Array, axis_name: str, root: int = 0
@@ -956,28 +987,10 @@ def tree_bcast(x: jax.Array, axis_name: str, root: int = 0
     n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
-    flat, pad, shape = _pad_chunk(x)
     kernel = functools.partial(_tree_bcast_kernel, axis_name, n,
                                int(root))
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((flat.size,), flat.dtype,
-                                       vma=frozenset({axis_name})),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.REGULAR((2,)),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            has_side_effects=True, collective_id=5,
-        ),
-        interpret=_interpret(),
-    )(flat)
-    if pad:
-        out = out[:-pad]
-    return out.reshape(shape)
+    return _flat_call(x, axis_name, kernel, 2,
+                      lambda r, dtype: _ring_sems(), "bcast")
 
 
 def _alltoall_kernel(axis_name: str, n: int, x_ref, out_ref,
@@ -992,6 +1005,7 @@ def _alltoall_kernel(axis_name: str, n: int, x_ref, out_ref,
     let a fast peer's later-step write satisfy an earlier step's wait
     and the kernel could exit before the straggler lands."""
     me = jax.lax.axis_index(axis_name)
+    entry_barrier(_all_peers(me, n))
     out_ref[me] = x_ref[me]
     for step in range(1, n):
         dst = jax.lax.rem(me + step, n)
@@ -1001,10 +1015,15 @@ def _alltoall_kernel(axis_name: str, n: int, x_ref, out_ref,
             send_sem=send_sem.at[step - 1],
             recv_sem=recv_sem.at[step - 1],
             device_id=dst,
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
+            device_id_type=_LOGICAL,
         )
         rdma.start()
         rdma.wait()
+
+
+def _peer_sems(n: int):
+    return [pltpu.SemaphoreType.DMA((n - 1,)),
+            pltpu.SemaphoreType.DMA((n - 1,))]
 
 
 def ring_alltoall(x: jax.Array, axis_name: str) -> jax.Array:
@@ -1015,28 +1034,16 @@ def ring_alltoall(x: jax.Array, axis_name: str) -> jax.Array:
         return x
     shape = x.shape[1:]
     flat = x.reshape(n, -1)
-    pad = (-flat.shape[1]) % 128
-    if pad:
-        flat = jnp.pad(flat, ((0, 0), (0, pad)))
+    lanes = flat.shape[1]
+    rows, seg = _plan_rows(lanes, flat.dtype, 2 * n)
     kernel = functools.partial(_alltoall_kernel, axis_name, n)
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(flat.shape, flat.dtype,
-                                       vma=frozenset({axis_name})),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.SemaphoreType.DMA((n - 1,)),
-            pltpu.SemaphoreType.DMA((n - 1,)),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            has_side_effects=True, collective_id=4,
-        ),
-        interpret=_interpret(),
-    )(flat)
-    if pad:
-        out = out[:, :-pad]
-    return out.reshape((n,) + shape)
+
+    def call(b):
+        return _vmem_call(kernel, b.shape, b.dtype, axis_name,
+                          _peer_sems(n), "alltoall")(b)
+
+    out = _by_segments(call, _tile(flat, rows), seg)
+    return _untile(out, lanes).reshape((n,) + shape)
 
 
 def _gather_kernel(axis_name: str, n: int, root: int, x_ref, out_ref,
@@ -1050,6 +1057,7 @@ def _gather_kernel(axis_name: str, n: int, root: int, x_ref, out_ref,
     shared slot could let one fast sender satisfy another's wait (same
     reasoning as the pairwise alltoall kernel)."""
     me = jax.lax.axis_index(axis_name)
+    entry_barrier(_all_peers(me, n))
     rel = jax.lax.rem(me - root + n, n)
 
     @pl.when(rel == 0)
@@ -1058,7 +1066,7 @@ def _gather_kernel(axis_name: str, n: int, root: int, x_ref, out_ref,
         for s in range(1, n):
             pltpu.semaphore_signal(
                 ready_sem, inc=1, device_id=jax.lax.rem(root + s, n),
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
+                device_id_type=_LOGICAL,
             )
         for s in range(1, n):
             src_dev = jax.lax.rem(root + s, n)
@@ -1067,7 +1075,7 @@ def _gather_kernel(axis_name: str, n: int, root: int, x_ref, out_ref,
                 send_sem=send_sems.at[s - 1],
                 recv_sem=recv_sems.at[s - 1],
                 device_id=src_dev,
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
+                device_id_type=_LOGICAL,
             ).wait_recv()
 
     @pl.when(rel != 0)
@@ -1080,7 +1088,7 @@ def _gather_kernel(axis_name: str, n: int, root: int, x_ref, out_ref,
                 send_sem=send_sems.at[s - 1],
                 recv_sem=recv_sems.at[s - 1],
                 device_id=root,
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
+                device_id_type=_LOGICAL,
             )
 
             @pl.when(rel == s)
@@ -1096,27 +1104,19 @@ def linear_gather(x: jax.Array, axis_name: str, root: int = 0
     n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x[None]
-    flat, pad, shape = _pad_chunk(x)
+    shape = x.shape
+    flat = x.reshape(1, -1)
+    lanes = flat.shape[1]
+    rows, seg = _plan_rows(lanes, flat.dtype, n + 1)
     kernel = functools.partial(_gather_kernel, axis_name, n, int(root))
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((n, flat.size), flat.dtype,
-                                       vma=frozenset({axis_name})),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.SemaphoreType.DMA((n - 1,)),
-            pltpu.SemaphoreType.DMA((n - 1,)),
-            pltpu.SemaphoreType.REGULAR,
-        ],
-        compiler_params=pltpu.CompilerParams(
-            has_side_effects=True, collective_id=10,
-        ),
-        interpret=_interpret(),
-    )(flat)
-    if pad:
-        out = out[:, :-pad]
-    return out.reshape((n,) + shape)
+
+    def call(b):
+        return _vmem_call(
+            kernel, (n,) + b.shape, b.dtype, axis_name,
+            _peer_sems(n) + [pltpu.SemaphoreType.REGULAR], "gather")(b)
+
+    out = _by_segments(call, _tile(flat, rows)[0], seg)
+    return _untile(out, lanes).reshape((n,) + shape)
 
 
 def _scatter_kernel(axis_name: str, n: int, root: int, x_ref, out_ref,
@@ -1127,6 +1127,7 @@ def _scatter_kernel(axis_name: str, n: int, root: int, x_ref, out_ref,
     receivers never write their landing buffer, they only read it after
     the recv semaphore fires, so an early-landing DMA is harmless."""
     me = jax.lax.axis_index(axis_name)
+    entry_barrier(_all_peers(me, n))
     rel = jax.lax.rem(me - root + n, n)
 
     @pl.when(rel == 0)
@@ -1140,7 +1141,7 @@ def _scatter_kernel(axis_name: str, n: int, root: int, x_ref, out_ref,
                 send_sem=send_sems.at[s - 1],
                 recv_sem=recv_sems.at[s - 1],
                 device_id=dst_dev,
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
+                device_id_type=_LOGICAL,
             )
             rdma.start()
             rdmas.append(rdma)
@@ -1155,7 +1156,7 @@ def _scatter_kernel(axis_name: str, n: int, root: int, x_ref, out_ref,
                 send_sem=send_sems.at[s - 1],
                 recv_sem=recv_sems.at[s - 1],
                 device_id=root,
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
+                device_id_type=_LOGICAL,
             )
 
             @pl.when(rel == s)
@@ -1172,28 +1173,16 @@ def linear_scatter(x: jax.Array, axis_name: str, root: int = 0
         return x[0]
     shape = x.shape[1:]
     flat = x.reshape(n, -1)
-    pad = (-flat.shape[1]) % 128
-    if pad:
-        flat = jnp.pad(flat, ((0, 0), (0, pad)))
+    lanes = flat.shape[1]
+    rows, seg = _plan_rows(lanes, flat.dtype, n + 1)
     kernel = functools.partial(_scatter_kernel, axis_name, n, int(root))
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((flat.shape[1],), flat.dtype,
-                                       vma=frozenset({axis_name})),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.SemaphoreType.DMA((n - 1,)),
-            pltpu.SemaphoreType.DMA((n - 1,)),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            has_side_effects=True, collective_id=11,
-        ),
-        interpret=_interpret(),
-    )(flat)
-    if pad:
-        out = out[:-pad]
-    return out.reshape(shape)
+
+    def call(b):
+        return _vmem_call(kernel, b.shape[1:], b.dtype, axis_name,
+                          _peer_sems(n), "scatter")(b)
+
+    out = _by_segments(call, _tile(flat, rows), seg)
+    return _untile(out[None], lanes)[0].reshape(shape)
 
 
 def ppermute_shift(x: jax.Array, axis_name: str, shift: int = 1
@@ -1204,39 +1193,27 @@ def ppermute_shift(x: jax.Array, axis_name: str, shift: int = 1
     n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
-    flat, pad, shape = _pad_chunk(x)
 
     def kernel(local_ref, out_ref, send_sem, recv_sem):
         me = jax.lax.axis_index(axis_name)
-        dst = jax.lax.rem(me + shift + n, n)
+        dst = jax.lax.rem(me + shift % n, n)
+        # symmetric peer set: my destination and my source
+        entry_barrier([dst, jax.lax.rem(me - shift % n + n, n)])
         rdma = pltpu.make_async_remote_copy(
             src_ref=local_ref,
             dst_ref=out_ref,
             send_sem=send_sem,
             recv_sem=recv_sem,
             device_id=dst,
-            device_id_type=pltpu.DeviceIdType.LOGICAL,
+            device_id_type=_LOGICAL,
         )
         rdma.start()
         rdma.wait()
 
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((flat.size,), flat.dtype,
-                                       vma=frozenset({axis_name})),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.SemaphoreType.DMA(()), pltpu.SemaphoreType.DMA(()),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            has_side_effects=True, collective_id=3,
-        ),
-        interpret=_interpret(),
-    )(flat)
-    if pad:
-        out = out[:-pad]
-    return out.reshape(shape)
+    return _flat_call(
+        x, axis_name, kernel, 2,
+        lambda r, dtype: [pltpu.SemaphoreType.DMA(()),
+                          pltpu.SemaphoreType.DMA(())], "shift")
 
 
 # ---------------------------------------------------------------------------
@@ -1252,7 +1229,7 @@ from .framework import COLL, CollComponent, compile_plan, rank_major_check  # no
 def _split_ring(b: jax.Array, n: int) -> tuple[jax.Array, int, tuple]:
     shape = b.shape
     flat = b.reshape(-1)
-    pad = (-flat.size) % (n * 128)
+    pad = (-flat.size) % n
     if pad:
         flat = jnp.pad(flat, (0, pad))
     return flat.reshape(n, -1), pad, shape

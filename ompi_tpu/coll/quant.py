@@ -341,15 +341,23 @@ def analytic_error_bound(per_rank: Any, axis_elems: int | None = None,
 # ---------------------------------------------------------------------------
 
 def _quant_rows(x):
-    """(rows, 128) f32 -> ((rows, 128) int8, (rows/128, 128) f32)."""
-    m = jnp.max(jnp.abs(x), axis=1, keepdims=True)
+    """(rows, 128) f32 -> ((rows, 128) int8, (rows/128, 128) f32).
+
+    Scale k of group g is row g*128+k's; the group axis stays leading
+    so Mosaic never moves rows between sublanes and lanes."""
+    g = x.shape[0] // 128
+    x3 = x.reshape(g, 128, 128)
+    m = jnp.max(jnp.abs(x3), axis=2)
     scale = jnp.where(m > 0, m / _INT8_LEVELS, 1.0)
-    q = jnp.clip(jnp.round(x / scale), -_INT8_LEVELS, _INT8_LEVELS)
-    return q.astype(jnp.int8), scale.reshape(-1, 128)
+    q = jnp.clip(jnp.round(x3 / scale[:, :, None]),
+                 -_INT8_LEVELS, _INT8_LEVELS)
+    return q.astype(jnp.int8).reshape(x.shape), scale
 
 
 def _dequant_rows(q, s):
-    return q.astype(jnp.float32) * s.reshape(-1, 1)
+    g = s.shape[0]
+    x3 = q.astype(jnp.float32).reshape(g, 128, 128) * s[:, :, None]
+    return x3.reshape(q.shape)
 
 
 def _quant_allreduce_kernel(axis_name, n, x_ref, out_ref,
@@ -362,9 +370,12 @@ def _quant_allreduce_kernel(axis_name, n, x_ref, out_ref,
     awaited), with dequant-accumulate-requant between hops."""
     from jax.experimental.pallas import tpu as pltpu
 
+    from . import pallas_ring as pr
+
     me = lax.axis_index(axis_name)
     right = lax.rem(me + 1, n)
     left = lax.rem(me - 1 + n, n)
+    pr.entry_barrier(pr._ring_peers(me, n))
 
     first = lax.rem(me - 1 + n, n)
     q0, s0 = _quant_rows(x_ref[first])
@@ -440,35 +451,37 @@ def allreduce_block_quant(b: jax.Array, axis_name: str, op: Any = "sum"
     shape = b.shape
     flat = b.astype(jnp.float32).reshape(-1)
     # Each ring block: (rows, 128) with rows % 128 == 0 so the f32
-    # scale-per-row group reshapes to whole (rows/128, 128) tiles.
-    quantum = n * 128 * 128
-    pad = (-flat.size) % quantum
+    # scale-per-row group lays out as whole (rows/128, 128) tiles.
+    lanes = -(-flat.size // n)
+    rows, seg = pr._plan_rows(lanes, jnp.float32, 2 * n + 1, quantum=128)
+    pad = n * rows * 128 - flat.size
     if pad:
         flat = jnp.pad(flat, (0, pad))
-    rows = flat.size // (n * 128)
-    blocks = flat.reshape(n, rows, 128)
     kernel = functools.partial(_quant_allreduce_kernel, axis_name, n)
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((n, rows, 128), jnp.float32,
-                                       vma=frozenset({axis_name})),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            pltpu.VMEM((2, rows, 128), jnp.int8),
-            pltpu.VMEM((2, rows // 128, 128), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.REGULAR((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.REGULAR((2,)),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            has_side_effects=True, collective_id=12,
-        ),
-        interpret=pr._interpret(),
-    )(blocks)
+
+    def call(blocks):
+        r = blocks.shape[-2]
+        return pl.pallas_call(
+            kernel,
+            out_shape=jax.ShapeDtypeStruct(blocks.shape, jnp.float32,
+                                           vma=frozenset({axis_name})),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[
+                pltpu.VMEM((2, r, 128), jnp.int8),
+                pltpu.VMEM((2, r // 128, 128), jnp.float32),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.REGULAR((2,)),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.REGULAR((2,)),
+            ],
+            compiler_params=pr._params("quant"),
+            interpret=pr._interpret(),
+        )(blocks)
+
+    out = pr._by_segments(call, flat.reshape(n, rows, 128), seg)
     flat_out = out.reshape(-1)
     if pad:
         flat_out = flat_out[:-pad]

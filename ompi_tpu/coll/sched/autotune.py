@@ -21,8 +21,8 @@ on-disk cache (sched/cache.py). Two scoring modes
 
 Health integration: candidates whose transport tier is QUARANTINED in
 the health ledger are never timed (or modeled) — a tuner probing a
-wedged device tunnel would hang exactly like the traffic it is trying
-to route around. The skip is recorded per sweep in the result and on
+wedged device would hang exactly like the traffic it is trying to
+route around. The skip is recorded per sweep in the result and on
 the ``sched_tune_skipped_quarantined`` SPC.
 """
 

@@ -311,68 +311,17 @@ def _validate_reduce_scatter(comm, fn: Callable, op, dtype, *,
     data = _payload(n, n * nelems, dtype,
                     block_constant=False).reshape(n, n, nelems)
     x = comm.put_rank_major(data)
-    # shard_map hands each rank a (1, n, nelems) slice; the [0]/[None]
-    # bracket keeps the P("ranks") in/out specs.
+    # compile_plan hands each rank its (n, nelems) block.
     ref_plan = compile_plan(
         comm, ("sched.validate.rs_ref", op.cache_key,
                str(np.dtype(dtype)), x.shape),
-        lambda b: spmd.reduce_scatter_ring(b[0], "ranks", op)[None])
+        lambda b: spmd.reduce_scatter_ring(b, "ranks", op))
     got_plan = compile_plan(
         comm, ("sched.validate", label, op.cache_key,
                str(np.dtype(dtype)), x.shape),
-        lambda b: fn(b[0], "ranks", op)[None], check_vma=check_vma)
+        lambda b: fn(b, "ranks", op), check_vma=check_vma)
     ref = np.asarray(jax.device_get(ref_plan(x)))
     got = np.asarray(jax.device_get(got_plan(x)))
-    return ref.dtype == got.dtype and ref.shape == got.shape \
-        and ref.tobytes() == got.tobytes()
-
-
-def _pallas_executable() -> bool:
-    """Can a Mosaic pallas_call actually run here — real TPU, or a jax
-    build whose interpret mode can emulate the remote DMA/semaphore
-    primitives on CPU? jax 0.4.x ships the primitives without the
-    emulation, so tier-1 there validates pallas codegen through the
-    table-program simulator instead."""
-    import jax
-
-    from .. import pallas_ring
-
-    return jax.default_backend() == "tpu" \
-        or pallas_ring.interpret_available()
-
-
-def _validate_simulated(comm, sched: Schedule, op, dtype, *,
-                        nelems: int) -> bool:
-    """Bit-identity check of a pallas-lowered schedule through
-    ``pallas_lower.simulate`` — the sequential executor that shares the
-    kernel's table program, slot discipline and store gating — against
-    the mathematical reduction (exact for the power-of-two payloads
-    regardless of combine order). Covers every decision ``analyze``
-    bakes into the kernel when Mosaic execution is unavailable."""
-    import functools
-
-    import jax.numpy as jnp
-
-    from . import pallas_lower
-    from ...ops import lookup as op_lookup
-
-    op = op_lookup(op)
-    n = sched.nranks
-    if comm.size != n:
-        raise ArgumentError(
-            f"schedule {sched.name!r} compiled for {n} ranks, comm has "
-            f"{comm.size}")
-    data = jnp.asarray(
-        _payload(n, sched.nchunks * nelems, dtype,
-                 block_constant=False).reshape(n, sched.nchunks, nelems))
-    got = np.asarray(pallas_lower.simulate(sched, data, op))
-    red = functools.reduce(op.combine, [data[k] for k in range(n)])
-    if sched.op == "reduce_scatter":
-        # REDUCE_SCATTER_ALGOS contract: rank k's result is chunk k. A
-        # schedule that lands a different chunk fails right here.
-        ref = np.asarray(jnp.stack([red[k] for k in range(n)]))
-    else:
-        ref = np.asarray(jnp.stack([red] * n))
     return ref.dtype == got.dtype and ref.shape == got.shape \
         and ref.tobytes() == got.tobytes()
 
@@ -410,10 +359,8 @@ def validate_schedule(comm, sched: Schedule, op, dtype, *,
     Pallas-lowered and Mosaic-primitive schedules are held to the same
     bit-identity bar on every dtype (bf16 included); only the vma
     *plan check* is exempted for them (``_needs_vma_exemption``) — the
-    byte comparison itself never is. When the pallas kernel cannot
-    execute at all (CPU on a jax build without Mosaic interpret mode —
-    ``_pallas_executable``), the check runs through the table-program
-    simulator, which preserves the bit-identity bar on the codegen."""
+    byte comparison itself never is. On the CPU backend the pallas
+    kernels run under Mosaic's TPU interpret mode."""
     quantized = sched.meta.get("primitive", "").startswith("quant") \
         or any(s.kind in ANNOTATIONS for s in sched.steps)
     if quantized and sched.meta.get("wire", "int8") != "bf16":
@@ -422,8 +369,6 @@ def validate_schedule(comm, sched: Schedule, op, dtype, *,
             wire=sched.meta.get("wire", "int8"),
             block=sched.meta.get("block"), nelems=nelems,
             label=f"sched:{sched.digest()}")
-    if sched.meta.get("lowering") == "pallas" and not _pallas_executable():
-        return _validate_simulated(comm, sched, op, dtype, nelems=nelems)
     check_vma = not _needs_vma_exemption(sched)
     if sched.op == "reduce_scatter":
         return _validate_reduce_scatter(

@@ -868,22 +868,32 @@ class TunedColl(XlaColl):
             if not breaker.quiet() or not health.LEDGER.quiet():
                 return None  # lazy OPEN->HALF_OPEN / quarantine
                 # cooldown are live transitions a memo would miss
-            fn = self._build_fast_allreduce(comm, x, op)
-            if fn is None:
+            built = self._build_fast_allreduce(comm, x, op)
+            if built is None:
                 return None
-            ent = cache[key] = (stamp, fn)
+            ent = cache[key] = (stamp,) + built
+        else:
+            # the build recorded the first call; repeats count here
+            from ..core.counters import SPC
+
+            SPC.record(ent[2])
         try:
             return ent[1](x)
         except ArgumentError:
             raise
         except Exception:  # commlint: allow(broadexcept)
+            if not breaker.enabled():
+                raise  # no tier fallback: the fault is the caller's
             # Tier fault under a memoized plan: forget the entry and
             # let the slow path re-route (and trip the breaker there).
             cache.pop(key, None)
             return None
 
     def _build_fast_allreduce(self, comm, x, op):
+        """(plan, SPC counter name) for the memo, or None when the slow
+        path must decide."""
         from ..core.counters import SPC
+        from . import breaker
 
         limit = _host_small_max.value
         if (0 < limit >= x.size * x.dtype.itemsize and op.predefined
@@ -899,14 +909,16 @@ class TunedColl(XlaColl):
                 return jax.device_put(np.broadcast_to(red, a.shape),
                                       buf.sharding)
 
-            return host_plan
+            return host_plan, "coll_allreduce_algo_host"
         try:
-            _algo, plan = self._allreduce_choice(comm, x, op)
+            algo, plan = self._allreduce_choice(comm, x, op)
         except ArgumentError:
             raise
         except Exception:  # commlint: allow(broadexcept)
+            if not breaker.enabled():
+                raise
             return None  # slow path surfaces the real error
-        return plan
+        return plan, f"coll_allreduce_algo_{algo}"
 
     def allreduce(self, comm, x, op):
         op = op_lookup(op)

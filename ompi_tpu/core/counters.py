@@ -64,7 +64,8 @@ class Histogram:
     nanoseconds falls in ``[2^b, 2^(b+1))``, so 64 buckets span 1 ns to
     ~584 years with ~2x resolution — enough to read p50/p99 off a
     latency distribution without storing samples. Percentiles
-    interpolate linearly inside the winning bucket."""
+    interpolate linearly inside the winning bucket, clamped to the
+    observed min/max."""
 
     __slots__ = ("name", "description", "unit", "counts", "count",
                  "total", "min", "max", "_lock")
@@ -115,7 +116,9 @@ class Histogram:
                 if seen + n >= target:
                     frac = (target - seen) / n
                     lo = float(1 << b)
-                    return (lo + frac * lo) * 1e-9  # within [2^b, 2^(b+1))
+                    est = (lo + frac * lo) * 1e-9  # within [2^b, 2^(b+1))
+                    # never outside what was observed (one sample: exact)
+                    return min(max(est, self.min), self.max)
                 seen += n
             return self.max
 

@@ -19,7 +19,7 @@ session lifecycle edges:
              namespace too — zero orphaned scopes
 
 Shared warm state (sched winner cache, fastpath rings, the device
-tunnel) is never scoped to a tenant, so none of this touches it: one
+handles) is never scoped to a tenant, so none of this touches it: one
 tenant's quarantine denies *its* scopes only, and ``is_denied`` for
 every other tenant keeps consulting (their scope, global) exactly as
 before.

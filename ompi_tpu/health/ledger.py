@@ -2,7 +2,7 @@
 
 The PR-5 circuit breaker (coll/breaker.py) is keyed (op, algo): a
 quant kernel fault opens *that* breaker, but the underlying cause —
-the device tunnel wedged, the shm segment torn — takes out every
+a device call wedged, the shm segment torn — takes out every
 algorithm riding the same transport **tier**. The ledger promotes the
 failure domain from (op, algo) to the tier itself, a small lattice of
 transport planes:

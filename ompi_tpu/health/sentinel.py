@@ -1,10 +1,10 @@
 """Health sentinel: progress-engine heartbeat + per-op stall deadlines.
 
 The breaker can only degrade a tier that *fails*; a tier that
-*wedges* (a dead device tunnel, a peer that stopped draining its
-ring) hangs the collective forever — exactly the BENCH_r03-r05
-failure the bench watchdog used to abort the whole run on. The
-sentinel turns a wedge into an ordinary tier fault:
+*wedges* (a device call that never returns, a peer that stopped
+draining its ring) hangs the collective forever — the failure the
+bench watchdog used to abort the whole run on. The sentinel turns a
+wedge into an ordinary tier fault:
 
 - **heartbeat** — ``core/progress`` stamps ``beat()`` on every sweep
   (injected via ``progress.set_heartbeat`` so core never imports

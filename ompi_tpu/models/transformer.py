@@ -39,11 +39,8 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..core import jax_compat
 from ..parallel import dp as dp_mod
 from ..parallel import overlap as overlap_mod
-
-jax_compat.ensure()
 from ..parallel import ep as ep_mod
 from ..parallel import pp as pp_mod
 from ..parallel import sp as sp_mod

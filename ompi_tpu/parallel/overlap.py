@@ -920,8 +920,7 @@ def jaxpr_backward_order(grad_fn, *args) -> tuple:
     gradient materializes first in the compiled backward.
 
     Returns leaf indices (into the flattened gradient pytree) in
-    production order. Requires
-    :func:`ompi_tpu.core.jax_compat.jaxpr_ordering_available`.
+    production order.
     """
     closed = jax.make_jaxpr(grad_fn)(*args)
     jaxpr = closed.jaxpr
@@ -939,13 +938,11 @@ def jaxpr_backward_order(grad_fn, *args) -> tuple:
 
 def readiness_order(grad_fn=None, args: tuple = ()) -> tuple:
     """The overlap session's readiness source: ``("jaxpr", order)``
-    from jax's real program ordering when the installed jax exposes it
-    (jax_compat-gated), else ``("marker", backward_order())`` — the
-    custom-VJP :func:`grad_marker` capture. Both name the same thing:
-    the sequence gradients materialize in during the backward pass."""
-    from ..core import jax_compat
-
-    if grad_fn is not None and jax_compat.jaxpr_ordering_available():
+    from jax's real program ordering when ``grad_fn`` traces, else
+    ``("marker", backward_order())`` — the custom-VJP
+    :func:`grad_marker` capture. Both name the same thing: the sequence
+    gradients materialize in during the backward pass."""
+    if grad_fn is not None:
         try:
             return ("jaxpr", jaxpr_backward_order(grad_fn, *args))
         except Exception:  # commlint: allow(broadexcept)

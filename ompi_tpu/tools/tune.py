@@ -114,23 +114,34 @@ def tune(comm, ops=None, min_bytes: int = 256,
         EXSCAN_ALGOS,
         SCATTER_ALGOS,
         _pallas_algos,
+        is_pallas_algo,
     )
 
-    _pallas_algos()  # pallas-vs-xla selection from measurement
+    import jax
+
+    # pallas-vs-xla selection from measurement, on a TPU only: on any
+    # other backend the Mosaic kernels run in interpret mode, whose
+    # timings measure the emulator and not the chip.
+    on_tpu = jax.default_backend() == "tpu"
+    if on_tpu:
+        _pallas_algos()
+
+    def space(algos, drop=()):
+        return {k: v for k, v in algos.items()
+                if k not in drop and (on_tpu or not is_pallas_algo(k))}
+
     spaces = {
-        "allreduce": {
-            k: v for k, v in ALLREDUCE_ALGOS.items()
-            if k not in ("gather_reduce", "ring_segmented")
-        },
-        "allgather": ALLGATHER_ALGOS,
-        "alltoall": ALLTOALL_ALGOS,
-        "bcast": BCAST_ALGOS,
-        "reduce": REDUCE_ALGOS,
-        "reduce_scatter": REDUCE_SCATTER_ALGOS,
-        "gather": GATHER_ALGOS,
-        "scatter": SCATTER_ALGOS,
-        "scan": SCAN_ALGOS,
-        "exscan": EXSCAN_ALGOS,
+        "allreduce": space(ALLREDUCE_ALGOS,
+                           ("gather_reduce", "ring_segmented")),
+        "allgather": space(ALLGATHER_ALGOS),
+        "alltoall": space(ALLTOALL_ALGOS),
+        "bcast": space(BCAST_ALGOS),
+        "reduce": space(REDUCE_ALGOS),
+        "reduce_scatter": space(REDUCE_SCATTER_ALGOS),
+        "gather": space(GATHER_ALGOS),
+        "scatter": space(SCATTER_ALGOS),
+        "scan": space(SCAN_ALGOS),
+        "exscan": space(EXSCAN_ALGOS),
     }
     ops = ops or list(spaces)
     out = {}

@@ -264,22 +264,17 @@ def test_partitioned_buckets_route_through_quant(quant_enabled):
 
 
 # ---------------------------------------------------------------------------
-# pallas fused kernel (skips where Mosaic interpret mode is absent)
+# pallas fused kernel (Mosaic interpret mode on the CPU mesh)
 # ---------------------------------------------------------------------------
 
-def _interpret_available() -> bool:
-    from jax.experimental.pallas import tpu as pltpu
-
-    return hasattr(pltpu, "InterpretParams")
-
-
-@pytest.mark.skipif(not _interpret_available(),
-                    reason="pltpu.InterpretParams unavailable "
-                           "(no Mosaic interpret mode in this jax)")
 def test_pallas_quant_allreduce_within_bound():
     from jax.sharding import Mesh, PartitionSpec as P
 
-    n = 8
+    # Four ranks: the interpret-mode emulator parks one XLA:CPU worker
+    # thread per emulated device, and at eight devices its buffer
+    # allocations starve the host pool on an 8-core machine (a hang of
+    # the emulator, not of the kernel's semaphore protocol).
+    n = 4
     data = _rand((n, 128 * 128), seed=11)  # one quantum per rank
     mesh = Mesh(np.array(jax.devices()[:n]), ("r",))
     fn = jax.jit(jax.shard_map(

@@ -1,7 +1,7 @@
 """Pallas lowering backend (coll/sched/pallas_lower): the dense
-chained round-uniform contract, codegen bit-identity via the table
-simulator (plus the real kernel under Mosaic interpret mode where the
-jax build has one), the device_pallas lattice tier with its medic
+chained round-uniform contract, codegen bit-identity of the real
+kernel under Mosaic interpret mode (and of the table simulator), the
+device_pallas lattice tier with its medic
 probe, autotuner quarantine discipline, the lowering-strategy
 telemetry, and the devicesem lint rule."""
 
@@ -101,11 +101,9 @@ def _pallas_programs(n):
 
 
 def test_pallas_schedules_bit_identical_via_oracle():
-    """Every pallas-lowered program must be bit-identical to the
-    mathematical reference across dtypes and ops. On a jax build
-    without Mosaic interpret mode validate_schedule routes through the
-    table-program simulator, which shares the kernel's slot/store
-    semantics; with one (or a TPU) the real kernel runs."""
+    """Every pallas-lowered program must be bit-identical to the ring
+    reference across dtypes and ops: the real kernel runs, under
+    Mosaic's interpret mode on the CPU mesh."""
     comm = mt.world()
     for s in _pallas_programs(comm.size):
         ir.check(s)
@@ -144,9 +142,6 @@ def test_simulate_shapes_and_reduce_scatter_ownership():
         pallas_lower.simulate(ir.ring(8), data[:, 0], "sum")
 
 
-@pytest.mark.skipif(not pallas_ring.interpret_available(),
-                    reason="this jax build has no Mosaic TPU interpret "
-                           "mode; the simulator oracle covers codegen")
 def test_pallas_kernels_execute_under_interpret_mode():
     comm = mt.world()
     for s in _pallas_programs(comm.size):

@@ -329,15 +329,13 @@ def _block_stack_loss():
 
 
 def test_jaxpr_and_marker_readiness_orders_agree():
-    """The jax_compat-gated jaxpr ordering and the grad_marker capture
-    must name the same backward schedule on the transformer block
-    stack: last block's gradients first."""
+    """The jaxpr ordering and the grad_marker capture must name the
+    same backward schedule on the transformer block stack: last
+    block's gradients first."""
     import jax
 
-    from ompi_tpu.core import jax_compat
     from ompi_tpu.parallel import overlap as ovl
 
-    assert jax_compat.jaxpr_ordering_available()
     loss, ws, x = _block_stack_loss()
 
     ovl.reset_capture()
@@ -360,14 +358,16 @@ def test_jaxpr_and_marker_readiness_orders_agree():
 def test_readiness_order_falls_back_to_marker(monkeypatch):
     import jax
 
-    from ompi_tpu.core import jax_compat
     from ompi_tpu.parallel import overlap as ovl
 
     loss, ws, x = _block_stack_loss()
     ovl.reset_capture()
     jax.grad(loss, argnums=(0, 1))(ws, x)
-    monkeypatch.setattr(jax_compat, "jaxpr_ordering_available",
-                        lambda: False)
+
+    def untraceable(*a, **k):
+        raise TypeError("grad_fn does not trace")
+
+    monkeypatch.setattr(ovl, "jaxpr_backward_order", untraceable)
     kind, order = ovl.readiness_order(jax.grad(loss), args=(ws, x))
     assert kind == "marker"
     assert order == ("blk3", "blk2", "blk1", "blk0")

@@ -327,3 +327,21 @@ def test_rules_file_dtype_band_matches_only_that_dtype(tmp_path):
             "coll_allreduce_algo_recursive_doubling", 0) == after
     finally:
         config.set("coll_tuned_rules_file", "")
+
+
+def test_tune_times_no_pallas_kernel_off_tpu(monkeypatch):
+    """Off a TPU the Mosaic kernels would run in interpret mode, whose
+    timings measure the emulator: the sweep leaves them out."""
+    from ompi_tpu.coll import tuned
+    from ompi_tpu.tools import tune
+
+    tuned._pallas_algos()  # registered or not, they must not be timed
+    seen = {}
+    monkeypatch.setattr(
+        tune, "sweep_op",
+        lambda comm, opname, algos, *a: seen.setdefault(opname, set(algos))
+        and [])
+    tune.tune(mt.world(), min_bytes=256, max_bytes=256, iters=1)
+    assert seen and "ring" in seen["allreduce"]
+    for names in seen.values():
+        assert not any(tuned.is_pallas_algo(n) for n in names), names

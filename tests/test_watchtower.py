@@ -40,6 +40,9 @@ from ompi_tpu.telemetry import watchtower
 from ompi_tpu.tools import benchgate, mpit
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+#: A small synthetic bench trajectory: five BENCH rounds (two clean,
+#: three host-only with rc != 0) and five MULTICHIP rounds.
+TRAJ = os.path.join(ROOT, "tests", "fixtures", "benchgate")
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -592,24 +595,24 @@ def test_benchgate_direction_and_regression_semantics():
 
 
 def test_benchgate_trajectory_loads_and_self_replay_passes():
-    rounds = benchgate.load_trajectory(ROOT)
+    rounds = benchgate.load_trajectory(TRAJ)
     assert len(rounds) >= 10
     best = benchgate.baselines(rounds)
     assert ("fabric_loopback", "p50_64B_us") in best
-    assert benchgate.main(["--root", ROOT, "--dry-run"]) == 0
+    assert benchgate.main(["--root", TRAJ, "--dry-run"]) == 0
     # the recorded trajectory itself passes its own ratchet (host-only
     # rc!=0 rounds ride the degraded-row excusal)
-    assert benchgate.main(["--root", ROOT, "--self"]) == 0
+    assert benchgate.main(["--root", TRAJ, "--self"]) == 0
 
 
 def test_benchgate_fails_synthetic_regression(tmp_path, capsys):
-    rounds = benchgate.load_trajectory(ROOT)
+    rounds = benchgate.load_trajectory(TRAJ)
     best = benchgate.baselines(rounds)[("fabric_loopback",
                                         "p50_64B_us")]
     bad = tmp_path / "bad.json"
     bad.write_text(json.dumps(
         {"rows": {"fabric_loopback": {"p50_64B_us": best * 10}}}))
-    assert benchgate.main(["--root", ROOT, "--current",
+    assert benchgate.main(["--root", TRAJ, "--current",
                            str(bad)]) == 1
     assert "RATCHET BREAK" in capsys.readouterr().out
     # the same regression tagged degraded is excused, not silent
@@ -617,19 +620,19 @@ def test_benchgate_fails_synthetic_regression(tmp_path, capsys):
     excused.write_text(json.dumps(
         {"rows": {"fabric_loopback": {"p50_64B_us": best * 10,
                                       "degraded": True}}}))
-    assert benchgate.main(["--root", ROOT, "--current",
+    assert benchgate.main(["--root", TRAJ, "--current",
                            str(excused)]) == 0
     assert "excused" in capsys.readouterr().out
     # at the baseline: clean pass
     ok = tmp_path / "ok.json"
     ok.write_text(json.dumps(
         {"rows": {"fabric_loopback": {"p50_64B_us": best}}}))
-    assert benchgate.main(["--root", ROOT, "--current",
+    assert benchgate.main(["--root", TRAJ, "--current",
                            str(ok)]) == 0
     # malformed current / empty trajectory: run failure, not a break
     broken = tmp_path / "broken.json"
     broken.write_text("not json {")
-    assert benchgate.main(["--root", ROOT, "--current",
+    assert benchgate.main(["--root", TRAJ, "--current",
                            str(broken)]) == 2
     assert benchgate.main(["--root", str(tmp_path / "nowhere")]) == 2
 
@@ -670,7 +673,8 @@ def test_lint_baseline_and_benchgate_gate_from_tier1():
     )
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-500:]
     r = subprocess.run(
-        [sys.executable, "bench.py", "--gate", "--dry-run"],
+        [sys.executable, "bench.py", "--gate", "--dry-run",
+         "--root", TRAJ],
         capture_output=True, text=True, cwd=ROOT, timeout=300,
         env={**os.environ, "JAX_PLATFORMS": "cpu"},
     )
