@@ -122,9 +122,8 @@ def tune(comm, ops=None, min_bytes: int = 256,
     # pallas-vs-xla selection from measurement, on a TPU only: on any
     # other backend the Mosaic kernels run in interpret mode, whose
     # timings measure the emulator and not the chip.
+    _pallas_algos()
     on_tpu = jax.default_backend() == "tpu"
-    if on_tpu:
-        _pallas_algos()
 
     def space(algos, drop=()):
         return {k: v for k, v in algos.items()

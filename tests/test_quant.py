@@ -270,10 +270,7 @@ def test_partitioned_buckets_route_through_quant(quant_enabled):
 def test_pallas_quant_allreduce_within_bound():
     from jax.sharding import Mesh, PartitionSpec as P
 
-    # Four ranks: the interpret-mode emulator parks one XLA:CPU worker
-    # thread per emulated device, and at eight devices its buffer
-    # allocations starve the host pool on an 8-core machine (a hang of
-    # the emulator, not of the kernel's semaphore protocol).
+    # Four ranks in this process; eight run in a subprocess below.
     n = 4
     data = _rand((n, 128 * 128), seed=11)  # one quantum per rank
     mesh = Mesh(np.array(jax.devices()[:n]), ("r",))
@@ -287,3 +284,50 @@ def test_pallas_quant_allreduce_within_bound():
     assert (np.abs(out[0] - data.sum(0)) <= bound).all()
     for r in range(1, n):
         np.testing.assert_array_equal(out[r], out[0])
+
+
+_QUANT_8_RANKS = """
+import jax
+jax.config.update("jax_num_cpu_devices", 8)
+import jax.numpy as jnp, numpy as np
+from jax.sharding import Mesh, PartitionSpec as P
+from ompi_tpu.coll import quant
+n = 8
+data = np.random.default_rng(11).standard_normal((n, 128 * 128)).astype(
+    np.float32)
+mesh = Mesh(np.array(jax.devices()[:n]), ("r",))
+fn = jax.jit(jax.shard_map(
+    lambda b: quant.allreduce_block_quant(b[0], "r", "sum")[None],
+    mesh=mesh, in_specs=(P("r"),), out_specs=P("r"), check_vma=False))
+out = np.asarray(fn(jnp.asarray(data)))
+bound = np.asarray(quant.analytic_error_bound(data))
+assert (np.abs(out[0] - data.sum(0)) <= bound).all()
+assert all((out[r] == out[0]).all() for r in range(1, n))
+print("QUANT8 OK")
+"""
+
+
+def test_pallas_quant_allreduce_8_ranks_with_a_wider_host_pool():
+    """The kernel's barrier and credit protocol at eight ranks.
+
+    In interpret mode every emulated device's program holds one thread
+    of XLA:CPU's host pool while it sits in an emulator callback, and
+    the emulator's buffer allocation (``np.array`` of a device value)
+    needs one more. A pool of exactly eight threads under eight emulated
+    devices therefore deadlocks with all eight parked in
+    ``_allocate_buffer``, while seven ranks on the same pool finish.
+    ``NPROC`` sizes that pool, so eight ranks run in a subprocess whose
+    pool has sixteen threads."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, NPROC="16", JAX_PLATFORMS="cpu",
+               PYTHONPATH=root + os.pathsep + os.environ.get("PYTHONPATH",
+                                                              ""))
+    proc = subprocess.run([sys.executable, "-c", _QUANT_8_RANKS], env=env,
+                          cwd=root, capture_output=True, text=True,
+                          timeout=240)
+    assert proc.returncode == 0 and "QUANT8 OK" in proc.stdout, \
+        proc.stdout[-2000:] + proc.stderr[-4000:]

@@ -286,6 +286,26 @@ def test_device_pallas_canary_registered_and_green(clean_health):
     assert prober.probe_tier("device_pallas")
 
 
+def test_device_pallas_canary_says_it_simulated_off_tpu(clean_health):
+    """No Mosaic compile is possible on the CPU backend: the canary
+    registered there is the table simulation, and every probe counts
+    that it simulated rather than passing as if a kernel had run."""
+    import jax
+
+    from ompi_tpu.core.counters import SPC
+    from ompi_tpu.health import prober
+
+    assert jax.default_backend() == "cpu"
+    prober.unregister_probe("device_pallas")
+    prober.ensure_builtin_probes()
+    assert prober.probes()["device_pallas"].startswith("simulated")
+    before = SPC.snapshot().get("health_device_pallas_simulated", 0)
+    assert prober.probe_tier("device_pallas")
+    assert prober.probe_tier("device_pallas")
+    after = SPC.snapshot().get("health_device_pallas_simulated", 0)
+    assert after == before + 2
+
+
 def test_supervisor_resurrects_quarantined_device_pallas(clean_health):
     import time
 
