@@ -1,0 +1,10 @@
+"""Median call-to-ready time (us) over every call of the latency group."""
+
+from perfbench import arith
+
+
+def read(r):
+    g = r.calls.group("lat")
+    if g is None:
+        return None
+    return arith.median(list(g.t2 - g.t0)) * 1e6
