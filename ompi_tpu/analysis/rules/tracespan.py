@@ -1,19 +1,20 @@
 """Trace-coverage rule.
 
 ``tracespan``: a public collective/p2p entry point in coll/ or pml/
-that dispatches outside the selection seams never lands on the
-commtrace timeline — the flight recorder shows a gap exactly where the
-interesting call happened. Components registered with the framework
-(``@COLL.register`` / ``@PML.register``) are covered automatically:
-trace/span.py wraps every vtable entry and the selected pml at
-selection time, so this rule skips them. What it flags is the
+that dispatches outside the communicator and the selection seams never
+lands on the commtrace timeline — the flight recorder shows a gap
+exactly where the interesting call happened. Components registered
+with the framework (``@COLL.register`` / ``@PML.register``) are covered
+automatically: ``Communicator._coll_call`` opens a span around every
+vtable dispatch and trace/span.py wraps the selected pml at selection
+time, so this rule skips them. What it flags is the
 *unregistered* surface — module-level helpers or ad-hoc classes that
 expose an entry-op name (``allreduce``, ``send``, ...) with no span or
 instant call in the body and no selection-time wrap to catch them.
 
 Evidence that satisfies the rule, anywhere in the function body:
-a call named ``span``/``instant``/``Span``/``coll_trace_id`` or a
-``traced_*`` helper from trace/span.py.
+a call named ``span``/``instant``/``Span``/``coll_trace_id`` or the
+pml wrap helper from trace/span.py.
 
 Suppression: ``# commlint: allow(tracespan)`` on the def line, for
 entry points that are deliberately span-free (pure-dispatch persistent
@@ -35,9 +36,7 @@ _ENTRY_OPS = frozenset(
 
 #: Call names that count as span evidence inside a body.
 _SPAN_CALLS = frozenset({
-    "span", "instant", "Span", "coll_trace_id",
-    "traced_coll_fn", "maybe_wrap_coll", "maybe_wrap_pml",
-    "maybe_wrap_part",
+    "span", "instant", "Span", "coll_trace_id", "maybe_wrap_pml",
 })
 
 #: Directories whose entry points the rule audits ('/'-normalised).
@@ -115,7 +114,7 @@ class TraceSpanRule(LintRule):
             if not _takes_comm(node):
                 continue
             if node in covered:
-                continue  # selection-time wrap covers registered comps
+                continue  # _coll_call / the pml wrap cover registered comps
             if _has_span_evidence(node):
                 continue
             if ctx.suppressed(node.lineno, self.NAME):

@@ -97,13 +97,7 @@ def select_for_comm(comm) -> dict[str, tuple[Any, Callable]]:
     # fault plan is armed, every vtable entry consults it on dispatch.
     from ..ft import inject
 
-    table = inject.maybe_wrap_coll(table)
-    # commtrace wraps outermost: every dispatch runs under a span whose
-    # trace_id all ranks derive identically (trace/span.py). The
-    # component half of each entry stays unwrapped.
-    from ..trace import span as tspan
-
-    return tspan.maybe_wrap_coll(table)
+    return inject.maybe_wrap_coll(table)
 
 
 # ---------------------------------------------------------------------------

@@ -30,6 +30,7 @@ from ..core.errors import ArgumentError
 from ..core.logging import get_logger
 from ..ops import Op, lookup as op_lookup
 from ..ops.op import _is_joint
+from ..trace.span import Span
 from . import spmd
 from .framework import COLL, CollComponent, compile_plan, rank_major_check
 from .xla import XlaColl, _dtype_key, _leaf_check
@@ -878,7 +879,8 @@ class TunedColl(XlaColl):
 
             SPC.record(ent[2])
         try:
-            return ent[1](x)
+            with Span("coll.launch", "coll"):
+                return ent[1](x)
         except ArgumentError:
             raise
         except Exception:  # commlint: allow(broadexcept)
@@ -904,10 +906,12 @@ class TunedColl(XlaColl):
             SPC.record("coll_allreduce_algo_host")
 
             def host_plan(buf):
-                a = np.asarray(buf)
+                with Span("coll.host_fetch", "coll"):
+                    a = np.asarray(buf)
                 red = ufunc.reduce(a, axis=0)
-                return jax.device_put(np.broadcast_to(red, a.shape),
-                                      buf.sharding)
+                with Span("coll.host_put", "coll"):
+                    return jax.device_put(np.broadcast_to(red, a.shape),
+                                          buf.sharding)
 
             return host_plan, "coll_allreduce_algo_host"
         try:
@@ -955,7 +959,8 @@ class TunedColl(XlaColl):
                     inject.kernel_fault("allreduce", algo,
                                         cid=comm.cid)
                     _probe_steps(comm, "allreduce", algo)
-                return plan(x)
+                with Span("coll.launch", "coll"):
+                    return plan(x)
 
             try:
                 out = sentinel.maybe_bounded(

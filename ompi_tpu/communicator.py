@@ -38,6 +38,7 @@ from .core.errors import (ArgumentError, CommError, HasErrhandler,
 from .core.info import Info
 from .core.logging import get_logger
 from .group import Group
+from .trace.span import Span, coll_trace_id
 
 logger = get_logger("comm")
 
@@ -80,6 +81,7 @@ class Communicator(HasAttributes, HasErrhandler):
     ) -> None:
         self.group = group
         self.cid = _next_cid()
+        self._span_args = {"cid": self.cid}
         self.name = name or f"comm{self.cid}"
         self.info = info or Info()
         self.parent_cid = parent_cid
@@ -196,43 +198,49 @@ class Communicator(HasAttributes, HasErrhandler):
     # -- collectives (dispatch through the per-comm vtable) ---------------
 
     def _coll_call(self, opname: str, *args, **kw):
-        self._check_alive()
-        from .core.counters import SPC
+        # Counter, span and histogram names interned once per comm: the
+        # f-string build cost ~1 us per call in r05 dispatch profiles —
+        # real money at small-message rates.
+        names = self.__dict__.setdefault("_coll_names", {})
+        interned = names.get(opname)
+        if interned is None:
+            interned = names[opname] = (f"coll_{opname}_calls",
+                                        f"coll.{opname}", f"coll_{opname}")
+        counter, span_name, hist = interned
+        # the span covers the whole call; every rank derives the same
+        # trace_id (trace/span.py)
+        with Span(span_name, "coll", coll_trace_id(self.cid), hist,
+                  self._span_args):
+            self._check_alive()
+            from .core.counters import SPC
 
-        entry = self._coll.get(opname)
-        if entry is None:
-            raise CommError(
-                f"{self.name}: no coll component provides {opname}"
-            )
-        component, fn = entry
-        # Counter names interned once per comm: the f-string build cost
-        # ~1 us per call in r05 dispatch profiles — real money at
-        # small-message rates.
-        names = self.__dict__.setdefault("_coll_spc_names", {})
-        counter = names.get(opname)
-        if counter is None:
-            counter = names[opname] = f"coll_{opname}_calls"
-        SPC.record(counter)
-        from .core import memchecker
+            entry = self._coll.get(opname)
+            if entry is None:
+                raise CommError(
+                    f"{self.name}: no coll component provides {opname}"
+                )
+            component, fn = entry
+            SPC.record(counter)
+            from .core import memchecker
 
-        if memchecker.enabled() and args:
-            memchecker.check_defined(args[0], f"{opname} buffer")
-        from .monitoring import MONITOR
+            if memchecker.enabled() and args:
+                memchecker.check_defined(args[0], f"{opname} buffer")
+            from .monitoring import MONITOR
 
-        if MONITOR.enabled:
-            nbytes = 0
-            if args:
-                import jax
+            if MONITOR.enabled:
+                nbytes = 0
+                if args:
+                    import jax
 
-                for leaf in jax.tree.leaves(args[0]):
-                    if hasattr(leaf, "nbytes"):
-                        nbytes += leaf.nbytes
-            MONITOR.record_coll(self.cid, opname, nbytes)
-        from .analysis import sanitizer
+                    for leaf in jax.tree.leaves(args[0]):
+                        if hasattr(leaf, "nbytes"):
+                            nbytes += leaf.nbytes
+                MONITOR.record_coll(self.cid, opname, nbytes)
+            from .analysis import sanitizer
 
-        if sanitizer.active():
-            sanitizer.record_coll(self, opname)
-        return fn(self, *args, **kw)
+            if sanitizer.active():
+                sanitizer.record_coll(self, opname)
+            return fn(self, *args, **kw)
 
     def allreduce(self, x, op="sum"):
         return self._coll_call("allreduce", x, op)

@@ -15,14 +15,16 @@ from typing import Any
 
 import jax.numpy as jnp
 
+from ..trace.span import Span
 from .op import Op, lookup
 
 
 def reduce_local(op: "Op | str", inbuf: Any, inoutbuf: Any) -> Any:
     """MPI_Reduce_local: combine two buffers on-device
     (reference: ompi/op + test/datatype/reduce_local.c)."""
-    op = lookup(op)
-    return op.combine(inoutbuf, inbuf)
+    with Span("op.reduce_local", "op"):
+        op = lookup(op)
+        return op.combine(inoutbuf, inbuf)
 
 
 def reduce_ranks(x, op: "Op | str"):

@@ -61,9 +61,6 @@ def select_for_comm(comm) -> PartComponent:
         from ..analysis import sanitizer
 
         _selected = sanitizer.maybe_wrap_part(_selected)
-        from ..trace import span as tspan
-
-        _selected = tspan.maybe_wrap_part(_selected)
     return _selected
 
 

@@ -49,8 +49,9 @@ _enable = config.register(
     description="Flight recorder + span tracing (always-on design; "
     "disable to shed the last few hundred ns per traced call)",
 )
+_DEFAULT_ENTRIES = 65536
 _entries = config.register(
-    "trace", "base", "ring_entries", type=int, default=8192,
+    "trace", "base", "ring_entries", type=int, default=_DEFAULT_ENTRIES,
     description="Flight-recorder ring capacity (rounded up to a power "
     "of two; oldest records are overwritten)",
 )
@@ -91,7 +92,7 @@ class FlightRecorder:
     """Lock-free ring of fixed-shape event records (see module doc)."""
 
     def __init__(self, capacity: Optional[int] = None) -> None:
-        cap = int(capacity or _entries.value or 8192)
+        cap = int(capacity or _entries.value or _DEFAULT_ENTRIES)
         cap = 1 << max(6, (cap - 1).bit_length())
         self._slots: list = [None] * cap
         self._mask = cap - 1
@@ -132,11 +133,6 @@ class FlightRecorder:
         out = [r for r in self._slots if r is not None]
         out.sort(key=lambda r: r[0])
         return out
-
-    def next_seq(self) -> int:
-        """Total records ever emitted (monotone; >= len(records))."""
-        n = next(self._seq)  # count() has no peek; burn one seq
-        return n
 
     def clear(self) -> None:
         self._slots = [None] * (self._mask + 1)

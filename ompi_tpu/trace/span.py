@@ -16,12 +16,20 @@ view. The ``+1``/shift keeps IDs disjoint from user tags and from the
 part framework's derived window, i.e. trace IDs live in the same tag
 namespace and cannot collide with traffic tags.
 
-Interposition happens at the selection seams faultline and the
-sanitizer already use: the coll vtable (coll/framework.select_for_comm),
-the selected PML (pml/framework), the part component (part/framework)
-and BML pair selection (btl/framework). Wrappers are installed
-unconditionally and gate on the recorder's enable cvar per dispatch, so
-toggling tracing needs no selection reset.
+Where the spans open: ``Communicator._coll_call`` opens ``coll.<op>``
+around every collective it dispatches, preamble included; the tuned
+allreduce opens ``coll.launch`` around the plan call, and its host tier
+``coll.host_fetch`` / ``coll.host_put`` around the device-to-host fetch
+and the ``device_put``; ``ops.reduce_local`` opens ``op.reduce_local``.
+The selected PML is wrapped at its selection seam (pml/framework). A
+span does nothing while the recorder's enable cvar is off, so toggling
+tracing needs no selection reset.
+
+While a ``jax.profiler`` session records, every span also opens a
+``TraceAnnotation`` of its name: the program's spans then sit on the
+profiler's host plane, on the clock of the device ops, and a device
+idle gap can be put down to the span open during it. The ring keeps
+its own ``perf_counter_ns`` stamps.
 
 Span begin/end also feed the Histogram pvar class (core/counters):
 ``coll_<op>`` / ``pml_send`` / ``pml_recv`` latency distributions with
@@ -34,6 +42,8 @@ import itertools
 import threading
 import time
 from typing import Any, Optional
+
+from jax.profiler import TraceAnnotation
 
 from ..core.counters import SPC
 from . import recorder
@@ -83,10 +93,12 @@ class Span:
     """Begin/end event pair. Plain __enter__/__exit__ (no
     contextmanager generator) keeps the per-span cost to two records
     plus bookkeeping. Nested spans inherit the trace ID and record the
-    enclosing span as ``parent``."""
+    enclosing span as ``parent``. With the recorder off a span records
+    nothing; while a profiler session records it is also a
+    ``TraceAnnotation`` (module doc)."""
 
     __slots__ = ("name", "cat", "trace_id", "span_id", "parent_id",
-                 "args", "hist", "t0_ns")
+                 "args", "hist", "t0_ns", "ann")
 
     def __init__(self, name: str, cat: str = "span",
                  trace_id: Optional[int] = None,
@@ -100,8 +112,11 @@ class Span:
         self.span_id = 0
         self.parent_id = 0
         self.t0_ns = 0
+        self.ann = None
 
     def __enter__(self) -> "Span":
+        if not recorder.enabled():
+            return self  # span_id stays 0: __exit__ records nothing
         st = _stack()
         parent = st[-1] if st else None
         if parent is not None:
@@ -112,22 +127,31 @@ class Span:
         a = {"trace_id": self.trace_id or 0}
         if self.args:
             a.update(self.args)
+        if TraceAnnotation.is_enabled():
+            self.ann = TraceAnnotation(self.name)
+            self.ann.__enter__()
         self.t0_ns = time.perf_counter_ns()
-        recorder.emit("B", self.name, cat=self.cat, span=self.span_id,
-                      parent=self.parent_id, args=a, t_ns=self.t0_ns)
+        # the ring's own emit: the module-level one repacks its keywords
+        recorder.get().emit("B", self.name, cat=self.cat,
+                            span=self.span_id, parent=self.parent_id,
+                            args=a, t_ns=self.t0_ns)
         st.append(self)
         return self
 
     def __exit__(self, et, ev, tb) -> bool:
+        if not self.span_id:
+            return False
         st = _stack()
         if st and st[-1] is self:
             st.pop()
         t1 = time.perf_counter_ns()
-        recorder.emit(
+        recorder.get().emit(
             "E", self.name, cat=self.cat, span=self.span_id,
             parent=self.parent_id, t_ns=t1,
             args={"error": et.__name__} if et is not None else None,
         )
+        if self.ann is not None:
+            self.ann.__exit__(et, ev, tb)
         if self.hist is not None:
             SPC.record_latency(self.hist, (t1 - self.t0_ns) * 1e-9)
         return False
@@ -153,34 +177,6 @@ def instant(name: str, cat: str = "event", **args: Any) -> None:
 
 # -- interposition wrappers --------------------------------------------------
 
-def traced_coll_fn(opname: str, fn):
-    """Wrap one coll vtable entry: each dispatch runs under a span
-    whose trace_id all ranks derive identically (module doc)."""
-    name = f"coll.{opname}"
-    hist = f"coll_{opname}"
-
-    def traced(comm, *a, **kw):
-        if not recorder.enabled():
-            return fn(comm, *a, **kw)
-        with Span(name, "coll", coll_trace_id(comm.cid), hist,
-                  {"cid": comm.cid}):
-            return fn(comm, *a, **kw)
-
-    traced.__name__ = f"traced_{opname}"
-    traced.__trace_host__ = fn  # introspection (tests, re-wrap guard)
-    return traced
-
-
-def maybe_wrap_coll(table: dict) -> dict:
-    """Interpose on every vtable entry (selection-seam pattern). The
-    component half of each entry is preserved — tests and tools
-    introspect ``comm._coll[op][0].NAME``."""
-    return {
-        op: (comp, traced_coll_fn(op, fn))
-        for op, (comp, fn) in table.items()
-    }
-
-
 class TracePml:
     """Pass-through PML recording p2p spans (vprotocol idiom: wraps the
     selected component; unknown attributes — including NAME — delegate
@@ -200,15 +196,11 @@ class TracePml:
         return type(self.host)
 
     def send(self, comm, value, dest, tag, source=None):
-        if not recorder.enabled():
-            return self.host.send(comm, value, dest, tag, source=source)
         with Span("pml.send", "pml", histogram="pml_send",
                   args={"cid": comm.cid, "peer": dest, "tag": tag}):
             return self.host.send(comm, value, dest, tag, source=source)
 
     def recv(self, comm, source, tag, *, dest):
-        if not recorder.enabled():
-            return self.host.recv(comm, source, tag, dest=dest)
         with Span("pml.recv", "pml", histogram="pml_recv",
                   args={"cid": comm.cid, "peer": source, "tag": tag}):
             return self.host.recv(comm, source, tag, dest=dest)
@@ -216,16 +208,11 @@ class TracePml:
     def isend(self, comm, value, dest, tag, source=None):
         # nonblocking: the span covers the post, not the transfer —
         # completion shows up as the progress engine's own events
-        if not recorder.enabled():
-            return self.host.isend(comm, value, dest, tag,
-                                   source=source)
         with Span("pml.isend", "pml",
                   args={"cid": comm.cid, "peer": dest, "tag": tag}):
             return self.host.isend(comm, value, dest, tag, source=source)
 
     def irecv(self, comm, source, tag, *, dest):
-        if not recorder.enabled():
-            return self.host.irecv(comm, source, tag, dest=dest)
         with Span("pml.irecv", "pml",
                   args={"cid": comm.cid, "peer": source, "tag": tag}):
             return self.host.irecv(comm, source, tag, dest=dest)
@@ -233,36 +220,3 @@ class TracePml:
 
 def maybe_wrap_pml(selected):
     return TracePml(selected)
-
-
-class TracePart:
-    """Pass-through part component: partitioned init calls become
-    instant events carried by the enclosing span (if any)."""
-
-    def __init__(self, host) -> None:
-        self.host = host
-
-    def __getattr__(self, name):
-        return getattr(self.host, name)
-
-    @property
-    def __class__(self):  # transparent proxy, same reasoning as TracePml
-        return type(self.host)
-
-    def psend_init(self, comm, value, partitions, dest, tag=0, *,
-                   source=None):
-        instant("part.psend_init", cat="part", cid=comm.cid, peer=dest,
-                tag=tag, partitions=partitions)
-        return self.host.psend_init(comm, value, partitions, dest, tag,
-                                    source=source)
-
-    def precv_init(self, comm, partitions, source, tag=0, *, dest,
-                   like=None):
-        instant("part.precv_init", cat="part", cid=comm.cid,
-                peer=source, tag=tag, partitions=partitions)
-        return self.host.precv_init(comm, partitions, source, tag,
-                                    dest=dest, like=like)
-
-
-def maybe_wrap_part(selected):
-    return TracePart(selected)

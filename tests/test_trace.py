@@ -2,9 +2,9 @@
 
 Covers: ring wraparound + lock-free concurrent writers, the binary
 record codec, deterministic cross-rank trace IDs, span nesting and
-histogram feeding, the selection-seam wrappers preserving component
-identity, the faultline injected=true drill (satellite 2), the
-Histogram pvar class, the signal-handler post-mortem dump, the native
+histogram feeding, the spans on the allreduce and reduce_local call
+paths and their forwarding into a profiler session, the faultline
+injected=true drill (satellite 2), the Histogram pvar class, the signal-handler post-mortem dump, the native
 tracering bridge, the <5% recorder-overhead ratchet (satellite 3), the
 Perfetto/merge exporters plus the 2-rank CLI acceptance run, and the
 ``tracespan`` commlint rule (satellite 5)."""
@@ -174,7 +174,7 @@ def test_coll_trace_id_deterministic_and_namespaced():
     assert tspan.coll_trace_id(7) >> 20 == 8
 
 
-# -- selection-seam wrappers ------------------------------------------------
+# -- spans on the call paths -----------------------------------------------
 
 @pytest.fixture(scope="module")
 def world():
@@ -183,13 +183,97 @@ def world():
     return mt.world()
 
 
-def test_coll_vtable_wrapped_component_identity_kept(world):
+def _begins(recs) -> dict:
+    """name -> (span_id, parent_id, trace_id) of each span begun."""
+    return {r[3]: (r[5], r[6], r[8]["trace_id"]) for r in recs
+            if r[2] == "B"}
+
+
+@pytest.mark.parametrize("elems,fast,children", [
+    (2, True, ["coll.host_fetch", "coll.host_put"]),  # 64 B: host tier
+    (1024, True, []),        # 32 KiB rank-major: the memoized plan
+    (1024, False, []),       # the routed slow path's plan(x)
+], ids=["host", "plan", "slow"])
+def test_allreduce_span_tree(world, elems, fast, children):
+    import jax.numpy as jnp
+
     comp, fn = world._coll["allreduce"]
-    assert hasattr(comp, "NAME")  # component half untouched
-    host = fn
-    while hasattr(host, "__trace_host__"):
-        host = host.__trace_host__
-    assert host is not fn  # the trace wrapper is installed
+    assert fn == getattr(comp, "allreduce")  # the vtable is not wrapped
+    saved = config.get("coll_tuned_fast_dispatch_cache")
+    config.set("coll_tuned_fast_dispatch_cache", fast)
+    try:
+        x = world.put_rank_major(
+            jnp.ones((world.size, elems), jnp.float32))
+        recorder.configure(256)
+        world.allreduce(x, op="sum")
+    finally:
+        config.set("coll_tuned_fast_dispatch_cache", saved)
+    recs = _records()
+    spans = _begins(recs)
+    assert set(spans) == {"coll.allreduce", "coll.launch", *children}
+    root, launch = spans["coll.allreduce"], spans["coll.launch"]
+    assert launch[1] == root[0] and launch[2] == root[2]
+    assert root[2] >> 20 == world.cid + 1
+    for name in children:
+        assert spans[name][1] == launch[0] and spans[name][2] == root[2]
+    # the root opens first and closes last: it covers the whole call
+    assert recs[0][2:4] == ("B", "coll.allreduce")
+    assert recs[-1][2:4] == ("E", "coll.allreduce")
+    assert [r for r in recs if r[2] == "E"][-2][3] == "coll.launch"
+
+
+def test_reduce_local_span():
+    import jax.numpy as jnp
+    from ompi_tpu import ops
+
+    a = jnp.arange(8, dtype=jnp.float32)
+    out = ops.reduce_local("sum", a, a)
+    assert float(out[-1]) == 14.0
+    recs = _records()
+    assert [(r[2], r[3], r[4]) for r in recs] == [
+        ("B", "op.reduce_local", "op"), ("E", "op.reduce_local", "op")]
+    assert recs[0][5] == recs[1][5] and recs[0][6] == 0
+
+
+def test_spans_reach_the_profiler_only_while_it_records(world, tmp_path,
+                                                       monkeypatch):
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    opened = []
+    real = tspan.TraceAnnotation
+
+    class Spy(real):
+        def __init__(self, name):
+            opened.append(name)
+            super().__init__(name)
+
+    monkeypatch.setattr(tspan, "TraceAnnotation", Spy)
+    x = world.put_rank_major(jnp.ones((world.size, 2), jnp.float32))
+    jax.block_until_ready(world.allreduce(x, op="sum"))
+    assert opened == []  # no profiler session: nothing forwarded
+    with jax.profiler.trace(str(tmp_path)):
+        jax.block_until_ready(world.allreduce(x, op="sum"))
+    assert opened == ["coll.allreduce", "coll.launch", "coll.host_fetch",
+                      "coll.host_put"]
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    events = {e.name: (e.start_ns, e.start_ns + e.duration_ns)
+              for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events
+              if e.name in opened}
+    assert set(events) == set(opened)
+    # nested on the profiler's clock as in the ring
+    for inner, outer in [("coll.launch", "coll.allreduce"),
+                         ("coll.host_fetch", "coll.launch"),
+                         ("coll.host_put", "coll.launch")]:
+        assert events[outer][0] <= events[inner][0]
+        assert events[inner][1] <= events[outer][1]
+    ring = [r[3] for r in _records() if r[2] == "B"]
+    assert ring[-4:] == opened
 
 
 def test_pml_wrapper_delegates_name(world):
