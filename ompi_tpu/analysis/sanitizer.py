@@ -41,7 +41,7 @@ from collections import Counter as _Counter
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
-from ..core import config
+from ..core import config, dispatch_epoch
 from ..core import request as _request
 from ..core.counters import SPC
 from ..core.errors import OmpiTpuError
@@ -343,6 +343,7 @@ def enable() -> Tracker:
     if _TRACKER is None:
         _TRACKER = Tracker()
         _request.set_tracker(_TRACKER)
+        dispatch_epoch.bump()  # lane entries skip record_coll
         # NOTE: deliberately does not set the enable cvar — programmatic
         # enable() covers one init/finalize cycle; only the cvar (user
         # config) makes the sanitizer sticky across re-inits.
@@ -386,6 +387,7 @@ def finalize_check() -> Optional[BaseException]:
     if t is not None:
         _TRACKER = None
         _request.set_tracker(None)
+        dispatch_epoch.bump()
         from ..part import framework as part_fw
         from ..pml import framework as pml_fw
 

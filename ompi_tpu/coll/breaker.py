@@ -37,7 +37,7 @@ from __future__ import annotations
 import threading
 from typing import Optional
 
-from ..core import clock
+from ..core import clock, dispatch_epoch
 from ..core import config
 from ..core.counters import SPC
 from ..core.logging import get_logger
@@ -85,20 +85,13 @@ class _Tier:
 
 _tiers: dict[tuple[str, str], _Tier] = {}
 _mu = threading.Lock()
-# Bumped on every recorded failure/success/reset. The tuned fast
-# dispatch cache stamps itself with this (plus config.generation());
-# any breaker activity invalidates the memoized route.
-_generation = 0
+# Every recorded failure, every success that changes a tier, a restore
+# and a reset bump the dispatch epoch: breaker activity invalidates the
+# memoized allreduce routes.
 
 
 def enabled() -> bool:
     return _enable.value
-
-
-def generation() -> int:
-    """Monotonic breaker-activity stamp (cache invalidation)."""
-    with _mu:
-        return _generation
 
 
 def quiet() -> bool:
@@ -154,9 +147,7 @@ def is_open(op: str, algo: str) -> bool:
 
 
 def record_failure(op: str, algo: str) -> None:
-    global _generation
     with _mu:
-        _generation += 1
         t = _get(op, algo)
         t.failures += 1
         if t.state == HALF_OPEN or t.failures >= _threshold.value:
@@ -174,10 +165,10 @@ def record_failure(op: str, algo: str) -> None:
             t.state = OPEN
             t.opened_at = clock.monotonic()
             t.probing = False
+        dispatch_epoch.bump()  # after quiet() can see the new state
 
 
 def record_success(op: str, algo: str) -> None:
-    global _generation
     if not _tiers:  # hot path: nothing ever tripped, skip the lock
         return
     with _mu:
@@ -185,7 +176,7 @@ def record_success(op: str, algo: str) -> None:
         if t is None:
             return
         if t.state != CLOSED or t.failures:
-            _generation += 1
+            dispatch_epoch.bump()
         if t.state != CLOSED:
             logger.info("breaker %s/%s: probe succeeded, CLOSED", op,
                         algo)
@@ -206,7 +197,6 @@ def on_tier_restored(tier: str) -> None:
     again, so close every (op, algo) breaker riding it — the next
     dispatch goes straight back to the fast tier instead of waiting
     out each breaker's own cooldown."""
-    global _generation
     if not _tiers:
         return
     from ..health.ledger import tier_of_algo
@@ -220,7 +210,7 @@ def on_tier_restored(tier: str) -> None:
                 t.probing = False
                 closed.append((op, algo))
         if closed:
-            _generation += 1
+            dispatch_epoch.bump()
     for op, algo in closed:
         logger.info("breaker %s/%s: closed by tier %r restore", op,
                     algo, tier)
@@ -272,7 +262,6 @@ def route(op: str, algo: str, *, deny: tuple = (),
 
 def reset() -> None:
     """Forget all tier state (tests / re-init)."""
-    global _generation
     with _mu:
-        _generation += 1
+        dispatch_epoch.bump()
         _tiers.clear()

@@ -29,7 +29,7 @@ import threading
 from functools import partial
 from typing import Optional
 
-from ...core import config
+from ...core import config, dispatch_epoch
 from ...core.logging import get_logger
 
 logger = get_logger("coll.sched")
@@ -97,9 +97,8 @@ class ScheduleCache:
         # generation.
         self._load_attempted: dict[str, bool] = {}
         self._config_gen = -1
-        # bumped on every content change; memoized dispatch plans
-        # (tuned._fast_allreduce) stamp it so a warm/tune invalidates
-        # them.
+        # bumped on every content change, with the dispatch epoch, so
+        # a warm/tune invalidates the memoized allreduce routes.
         self._generation = 0
         # shared-read accounting by consumer scope ("tenant:<id>" /
         # "global"): the winner table warms ONCE per controller and
@@ -136,6 +135,7 @@ class ScheduleCache:
         with self._mu:
             self._entries[key] = ent
             self._generation += 1
+            dispatch_epoch.bump()
 
     def bump(self, key: str, algorithm: str, *, schedule: str = "",
              source: str = "retune", tune_ms: Optional[float] = None,
@@ -148,9 +148,9 @@ class ScheduleCache:
         """Install a new winner as a **version-bumped** entry: the
         prior winner survives one level deep under ``"previous"`` so a
         bad retune can be rolled back. Never mutates the old entry in
-        place — a memoized dispatch plan stamped with the previous
-        cache generation keeps running its old schedule until its memo
-        invalidates. Returns the new version number."""
+        place — a memoized allreduce route built under the previous
+        dispatch epoch keeps running its old schedule until its next
+        dispatch sees the bump. Returns the new version number."""
         new = {"algorithm": algorithm, "schedule": schedule,
                "source": source}
         if tile_bytes is not None:
@@ -187,6 +187,7 @@ class ScheduleCache:
                 }
             self._entries[key] = new
             self._generation += 1
+            dispatch_epoch.bump()
             return new["version"]
 
     def rollback(self, key: str) -> bool:
@@ -213,6 +214,7 @@ class ScheduleCache:
                     restored[carry] = ent[carry]
             self._entries[key] = restored
             self._generation += 1
+            dispatch_epoch.bump()
             return True
 
     def set_baseline(self, key: str, p50_us: float) -> None:
@@ -254,6 +256,7 @@ class ScheduleCache:
             self._scope_reads.clear()
             self._config_gen = -1
             self._generation += 1
+            dispatch_epoch.bump()
 
     def generation(self) -> int:
         """Content-change counter (see __init__)."""
@@ -333,6 +336,7 @@ class ScheduleCache:
                     loaded += 1
             if loaded:
                 self._generation += 1
+                dispatch_epoch.bump()
         return loaded
 
     def ensure_loaded(self, topo_fp: str, nranks: int) -> None:

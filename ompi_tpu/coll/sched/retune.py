@@ -12,8 +12,9 @@ mechanisms:
     algorithm is *excluded* from the sweep: the live measurement just
     falsified the model's prediction for it, so re-scoring it with the
     same model would deterministically re-elect it. The bump raises
-    the cache generation, so memoized dispatch plans
-    (``tuned._fast_allreduce``) re-consult at their next dispatch —
+    the cache generation and the dispatch epoch, so memoized
+    allreduce routes (the communicator's lane) re-consult at their
+    next dispatch —
     a schedule is never mutated mid-flight.
 
 topology penalties

@@ -30,7 +30,7 @@ from __future__ import annotations
 import threading
 from typing import Optional
 
-from ...core import config
+from ...core import config, dispatch_epoch
 from ...core.counters import SPC
 from ...core.logging import get_logger
 
@@ -48,25 +48,18 @@ _target_var = config.register(
 _mu = threading.Lock()
 _targets: dict[str, float] = {}
 _violation_s: dict[str, float] = {}
-_gen = 0
 
 
 def set_target(scope: str, p50_us: Optional[float]) -> None:
-    """Per-scope SLO override (None/0 clears it). Bumps the module
-    generation so memoized dispatch plans re-consult."""
-    global _gen
+    """Per-scope SLO override (None/0 clears it). Bumps the dispatch
+    epoch so memoized allreduce routes re-consult (the global cvar
+    rides the config mutation's bump)."""
     with _mu:
         if not p50_us:
             _targets.pop(str(scope), None)
         else:
             _targets[str(scope)] = float(p50_us)
-        _gen += 1
-
-
-def generation() -> int:
-    """Target-change counter (tuned._fast_allreduce stamps it; the
-    global cvar rides config.generation() instead)."""
-    return _gen
+        dispatch_epoch.bump()
 
 
 def target_for(scope: Optional[str] = None) -> float:
@@ -127,15 +120,14 @@ def violation_minutes() -> dict[str, float]:
 
 
 def reset_for_testing() -> None:
-    global _gen
     with _mu:
         _targets.clear()
         _violation_s.clear()
-        _gen += 1
+        dispatch_epoch.bump()
 
 
 __all__ = [
-    "frontier_pick", "generation", "note_violation",
+    "frontier_pick", "note_violation",
     "reset_for_testing", "set_target", "target_for", "targets",
     "violation_minutes",
 ]

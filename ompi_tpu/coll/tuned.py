@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core import config
+from ..core import config, dispatch_epoch
 from ..core.errors import ArgumentError
 from ..core.logging import get_logger
 from ..ops import Op, lookup as op_lookup
@@ -93,12 +93,15 @@ _alltoall_large = _V("alltoall_large_msg", type=int, default=32 << 10,
                      description="Alltoall: bytes/dest above which "
                                  "pairwise exchange is used")
 _fast_cache_var = _V("fast_dispatch_cache", type=bool, default=True,
-                     description="Memoize the routed allreduce dispatch "
-                                 "per (comm, shape, dtype, op): repeat "
-                                 "calls skip the decision pipeline "
-                                 "entirely. Invalidated by any config "
-                                 "mutation or breaker activity; bypassed "
-                                 "while faultline is armed")
+                     description="Route plain-array allreduces through "
+                                 "the fast path (host tier for tiny "
+                                 "payloads) and memoize the route in the "
+                                 "communicator's lane per (shape, dtype, "
+                                 "op): repeat calls skip the decision "
+                                 "pipeline entirely. Invalidated by the "
+                                 "dispatch epoch (config, breaker, "
+                                 "health, sched cache, SLO, faultline, "
+                                 "debug hooks, vtable re-selection)")
 _host_small_max = _V("host_small_max_bytes", type=int, default=4096,
                      description="Fully-addressable allreduces at or "
                                  "below this many bytes reduce on the "
@@ -835,64 +838,59 @@ class TunedColl(XlaColl):
         "max": np.maximum, "min": np.minimum,
     }
 
-    def _fast_allreduce(self, comm, x, op):
-        """Memoized hot-path dispatch: the routed-and-compiled plan for
-        (shape, dtype, op) is cached on the comm and repeat calls skip
-        the whole decision pipeline (~hundreds of us of rules, breaker
-        walk, key building and SPC f-strings per call in r05 profiles).
-        Tiny fully-addressable payloads get the host tier instead — a
-        numpy reduction over the rank axis plus one device_put beats an
-        XLA program launch below ~4 KiB. Returns the result, or None
-        when the slow path must run (cache disabled/invalid, pytree
-        input, faultline armed, breaker non-quiet)."""
+    def _fast_allreduce(self, comm, x, op, given):
+        """The fast route for a plain rank-major array: the host tier
+        for tiny fully-addressable payloads (a numpy reduction over the
+        rank axis plus one device_put beats an XLA program launch below
+        ~4 KiB), else the routed compiled plan. The route is built here
+        once and memoized in the communicator's allreduce lane, keyed
+        on ``given`` (the op as the caller passed it); repeat calls
+        never reach this method until the dispatch epoch moves.
+        Returns the result, or None when the slow path must run (route
+        disabled, pytree input, faultline armed, breaker or health
+        ledger non-quiet: their lazy transitions are live state a memo
+        would miss)."""
         if not _fast_cache_var.value or not isinstance(x, jax.Array):
             return None
         if x.ndim < 1 or x.shape[0] != comm.size:
             return None  # slow path raises the proper ArgumentError
         from ..ft import inject
-
-        if inject.armed():
-            return None  # every drill must see the real dispatch
         from ..health import ledger as health
         from . import breaker
 
-        from .sched import cache as sched_cache, slo as sched_slo
+        if inject.armed() or not breaker.quiet() \
+                or not health.LEDGER.quiet():
+            return None
+        epoch = dispatch_epoch.value  # read before the route is built
+        built = self._build_fast_allreduce(comm, x, op)
+        if built is None:
+            return None
+        plan, counter = built
+        key = comm._lane_store(TunedColl.allreduce, x, given,
+                               (epoch, plan, counter, self._launch_fast))
+        return self._launch_fast(comm, plan, x, given, key)
 
-        stamp = (config.generation(), breaker.generation(),
-                 health.LEDGER.generation(),
-                 sched_cache.CACHE.generation(),
-                 sched_slo.generation())
-        cache = comm.__dict__.setdefault("_tuned_fast", {})
-        key = (x.shape, x.dtype.name, op.cache_key)
-        ent = cache.get(key)
-        if ent is None or ent[0] != stamp:
-            if not breaker.quiet() or not health.LEDGER.quiet():
-                return None  # lazy OPEN->HALF_OPEN / quarantine
-                # cooldown are live transitions a memo would miss
-            built = self._build_fast_allreduce(comm, x, op)
-            if built is None:
-                return None
-            ent = cache[key] = (stamp,) + built
-        else:
-            # the build recorded the first call; repeats count here
-            from ..core.counters import SPC
-
-            SPC.record(ent[2])
+    def _launch_fast(self, comm, plan, x, op, key):
+        """Run a fast-route plan (a lane hit, or the call that built
+        it). A tier fault under it drops the lane entry and re-routes
+        the call through the slow path, whose retry loop trips the
+        breaker if the tier faults again; with the breaker off the
+        fault is the caller's."""
         try:
             with Span("coll.launch", "coll"):
-                return ent[1](x)
+                return plan(x)
         except ArgumentError:
             raise
         except Exception:  # commlint: allow(broadexcept)
+            from . import breaker
+
             if not breaker.enabled():
-                raise  # no tier fallback: the fault is the caller's
-            # Tier fault under a memoized plan: forget the entry and
-            # let the slow path re-route (and trip the breaker there).
-            cache.pop(key, None)
-            return None
+                raise
+            comm._lane.pop(key, None)
+            return self._routed_allreduce(comm, x, op_lookup(op))
 
     def _build_fast_allreduce(self, comm, x, op):
-        """(plan, SPC counter name) for the memo, or None when the slow
+        """(plan, SPC counter name) for the lane, or None when the slow
         path must decide."""
         from ..core.counters import SPC
         from . import breaker
@@ -925,11 +923,16 @@ class TunedColl(XlaColl):
         return plan, f"coll_allreduce_algo_{algo}"
 
     def allreduce(self, comm, x, op):
-        op = op_lookup(op)
+        given, op = op, op_lookup(op)
         if comm.size > 1:
-            out = self._fast_allreduce(comm, x, op)
+            out = self._fast_allreduce(comm, x, op, given)
             if out is not None:
                 return out
+        return self._routed_allreduce(comm, x, op)
+
+    def _routed_allreduce(self, comm, x, op):
+        """The decision pipeline on every call, with the breaker and
+        sentinel retry loop: tier faults degrade to the next tier."""
         x = _leaf_check(comm, x)
         if comm.size == 1:
             return x

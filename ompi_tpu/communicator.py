@@ -17,6 +17,10 @@ comm_cid.c). Design mapping:
 - Compiled collective plans are cached per (op, algorithm, shape, dtype)
   — the TPU answer to ob1's latency tricks (SURVEY §7 hard parts:
   "persistent, pre-compiled collective plans").
+- The allreduce lane (`self._lane`): the plan the tuned layer routed for
+  a plain array's (shape, dtype, op), memoized under the process-wide
+  dispatch epoch (core/dispatch_epoch); a repeat call costs one dict
+  lookup and one int compare before the plan launch.
 
 Driver-mode buffer convention ("rank-major"): a collective argument is a
 jax.Array whose leading axis is the rank index, sharded one block per
@@ -29,10 +33,12 @@ import itertools
 import threading
 from typing import Any, Optional, Sequence
 
+import jax
 import numpy as np
 
-from .core import config
+from .core import config, dispatch_epoch
 from .core.attributes import HasAttributes
+from .core.counters import SPC
 from .core.errors import (ArgumentError, CommError, HasErrhandler,
                           RankError, RevokedError)
 from .core.info import Info
@@ -97,6 +103,9 @@ class Communicator(HasAttributes, HasErrhandler):
         self.devices = [p.device for p in self.procs]
         self._mesh = None
         self._plan_cache: dict[tuple, Any] = {}
+        # (shape, dtype, op as given) -> (epoch, plan, algo counter,
+        # launch): see allreduce()
+        self._lane: dict[tuple, tuple] = {}
         self._coll: dict[str, tuple[Any, Any]] = {}
         self._pml = None
         self.topo = None  # attached by topo framework (cart/graph)
@@ -109,6 +118,7 @@ class Communicator(HasAttributes, HasErrhandler):
         from .coll.framework import select_for_comm as coll_select
 
         self._coll = coll_select(self)
+        dispatch_epoch.bump()  # lane entries stand in for the old vtable
 
     # -- basic accessors --------------------------------------------------
 
@@ -212,8 +222,6 @@ class Communicator(HasAttributes, HasErrhandler):
         with Span(span_name, "coll", coll_trace_id(self.cid), hist,
                   self._span_args):
             self._check_alive()
-            from .core.counters import SPC
-
             entry = self._coll.get(opname)
             if entry is None:
                 raise CommError(
@@ -243,7 +251,52 @@ class Communicator(HasAttributes, HasErrhandler):
             return fn(self, *args, **kw)
 
     def allreduce(self, x, op="sum"):
+        # The lane: a plain array whose (shape, dtype, op) the tuned
+        # layer already routed within this dispatch epoch skips the
+        # vtable, the op lookup, the gates and the route. A miss, or any
+        # state the lane cannot prove unchanged, takes _coll_call, which
+        # refills the lane through TunedColl (see _lane_store).
+        if isinstance(x, jax.Array):
+            key = (x.shape, x.dtype, op)
+            try:
+                ent = self._lane.get(key)
+            except TypeError:  # an unhashable op: _coll_call names it
+                ent = None
+            if ent is not None and ent[0] == dispatch_epoch.value:
+                with Span("coll.allreduce", "coll",
+                          coll_trace_id(self.cid), "coll_allreduce",
+                          self._span_args):
+                    self._check_alive()
+                    SPC.record("coll_allreduce_calls")
+                    SPC.record(ent[2])
+                    SPC.record("coll_allreduce_lane_hits")
+                    return ent[3](self, ent[1], x, op, key)
         return self._coll_call("allreduce", x, op)
+
+    def _lane_store(self, fn, x, op, ent) -> Optional[tuple]:
+        """Memoize a fast-route entry ``(epoch, plan, algo counter,
+        launch)`` that the vtable function ``fn`` just built for the
+        plain array ``x``; returns its key, or None when the lane must
+        stay out: the vtable dispatches through a wrapper or another
+        component, or a per-call hook (memchecker, MONITOR, sanitizer)
+        is on. Enabling any of them bumps the dispatch epoch, so a
+        stored entry never skips a hook turned on after it."""
+        from .analysis import sanitizer
+        from .core import memchecker
+        from .monitoring import MONITOR
+
+        vt = self._coll.get("allreduce")
+        if (vt is None or getattr(vt[1], "__func__", None) is not fn
+                or memchecker.enabled() or MONITOR.enabled
+                or sanitizer.active()):
+            return None
+        key = (x.shape, x.dtype, op)
+        try:
+            self._lane[key] = ent
+        except TypeError:  # an unhashable op
+            return None
+        SPC.record("coll_allreduce_lane_builds")
+        return key
 
     def bcast(self, x, root: int = 0):
         return self._coll_call("bcast", x, self.check_rank(root))
@@ -317,7 +370,9 @@ class Communicator(HasAttributes, HasErrhandler):
         return DeviceRequest(result)
 
     def iallreduce(self, x, op="sum"):
-        return self._icoll("allreduce", x, op)
+        from .coll.framework import DeviceRequest
+
+        return DeviceRequest(self.allreduce(x, op))
 
     def ibcast(self, x, root: int = 0):
         return self._icoll("bcast", x, self.check_rank(root))
@@ -590,6 +645,7 @@ class Communicator(HasAttributes, HasErrhandler):
     def free(self) -> None:
         self.free_attrs()
         self._plan_cache.clear()
+        self._lane.clear()
         if self._pml is not None and hasattr(self._pml, "comm_freed"):
             self._pml.comm_freed(self)
         self._freed = True

@@ -26,6 +26,8 @@ import os
 import threading
 from typing import Any, Callable, Iterable, Optional
 
+from . import dispatch_epoch
+
 ENV_PREFIX = "OMPITPU_MCA_"
 PARAMS_FILE_ENV = "OMPITPU_PARAMS_FILE"
 
@@ -134,9 +136,9 @@ class VarRegistry:
         self._file_values: dict[str, str] = {}
         self._files_loaded = False
         # Bumped on every post-registration mutation (set /
-        # set_if_unset / load_param_file / reset). Fast-path caches
-        # (coll/tuned's memoized dispatch) key their validity on this
-        # instead of re-reading every cvar per call.
+        # set_if_unset / load_param_file / reset), with the dispatch
+        # epoch: the allreduce lane relies on it instead of re-reading
+        # every cvar per call; the sched cache reloads on it.
         self._generation = 0
 
     # -- registration -----------------------------------------------------
@@ -231,7 +233,7 @@ class VarRegistry:
                     var._apply(
                         self._file_values[var.full_name], VarSource.FILE
                     )
-            self._generation += 1
+            self._bump()
 
     # -- access -----------------------------------------------------------
 
@@ -251,7 +253,7 @@ class VarRegistry:
             raise PermissionError(f"{full_name} is read-only")
         var._apply(value, VarSource.API)
         with self._lock:
-            self._generation += 1
+            self._bump()
 
     def set_if_unset(self, full_name: str, value: Any) -> None:
         var = self._vars.get(full_name)
@@ -260,7 +262,11 @@ class VarRegistry:
         if var.source == VarSource.DEFAULT:
             var._apply(value, VarSource.API)
             with self._lock:
-                self._generation += 1
+                self._bump()
+
+    def _bump(self) -> None:
+        self._generation += 1
+        dispatch_epoch.bump()
 
     def generation(self) -> int:
         """Monotonic mutation counter (cache-invalidation stamp)."""
@@ -299,7 +305,7 @@ class VarRegistry:
             self._vars.clear()
             self._file_values.clear()
             self._files_loaded = False
-            self._generation += 1
+            self._bump()
 
 
 # The process-global registry (the reference has exactly one, too).

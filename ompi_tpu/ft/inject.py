@@ -91,7 +91,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
-from ..core import config
+from ..core import config, dispatch_epoch
 from ..core.counters import SPC
 from ..core.errors import OmpiTpuError
 from ..core.logging import get_logger
@@ -414,6 +414,7 @@ def arm(specs=None, *, seed: Optional[int] = None) -> FaultPlan:
         FaultPlan(specs, seed=seed)
     _WEDGE_EV.clear()  # wedges in this plan will park
     _PLAN = p
+    dispatch_epoch.bump()  # every drill must see the real dispatch
     _reset_selections()
     logger.info("faultline armed: %d spec(s), seed=%d", len(p.specs),
                 p.seed)
@@ -425,6 +426,7 @@ def disarm() -> Optional[FaultPlan]:
     global _PLAN
     p = _PLAN
     _PLAN = None
+    dispatch_epoch.bump()
     _WEDGE_EV.set()  # release every wedged thread
     if p is not None:
         _reset_selections()

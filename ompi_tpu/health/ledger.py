@@ -55,7 +55,7 @@ import threading
 from typing import Callable, Optional
 
 from ..core import clock
-from ..core import config
+from ..core import config, dispatch_epoch
 from ..core.counters import SPC
 from ..core.logging import get_logger
 
@@ -152,7 +152,7 @@ class Ledger:
 
     def quiet(self) -> bool:
         """True when every tracked tier is HEALTHY — the precondition
-        for memoized routing (tuned's fast dispatch cache)."""
+        for memoized routing (the communicator's allreduce lane)."""
         return not self._any_unhealthy
 
     def tracked(self) -> bool:
@@ -184,6 +184,9 @@ class Ledger:
         self._any_unhealthy = any(
             x.state != HEALTHY for x in self._entries.values()
         )
+        # after quiet() can see the new state: a route memoized under
+        # the new epoch was built against it
+        dispatch_epoch.bump()
         from ..trace import span as tspan
 
         tspan.instant(f"health.{to_state}", cat="health", tier=tier,
@@ -437,6 +440,7 @@ class Ledger:
                 self._any_unhealthy = any(
                     x.state != HEALTHY for x in self._entries.values()
                 )
+                dispatch_epoch.bump()
         return len(keys)
 
     def seed_scope(self, scope: str, *,
@@ -512,6 +516,7 @@ class Ledger:
             self._generation += 1
             self._any_tracked = False
             self._any_unhealthy = False
+            dispatch_epoch.bump()
             self._restore_cbs.clear()
             self._pending_restored = []
 

@@ -17,8 +17,9 @@ part framework's derived window, i.e. trace IDs live in the same tag
 namespace and cannot collide with traffic tags.
 
 Where the spans open: ``Communicator._coll_call`` opens ``coll.<op>``
-around every collective it dispatches, preamble included; the tuned
-allreduce opens ``coll.launch`` around the plan call, and its host tier
+around every collective it dispatches, preamble included, and
+``Communicator.allreduce`` opens ``coll.allreduce`` itself for a lane
+hit; the tuned allreduce opens ``coll.launch`` around the plan call, and its host tier
 ``coll.host_fetch`` / ``coll.host_put`` around the device-to-host fetch
 and the ``device_put``; ``ops.reduce_local`` opens ``op.reduce_local``.
 The selected PML is wrapped at its selection seam (pml/framework). A

@@ -189,12 +189,17 @@ def _begins(recs) -> dict:
             if r[2] == "B"}
 
 
-@pytest.mark.parametrize("elems,fast,children", [
-    (2, True, ["coll.host_fetch", "coll.host_put"]),  # 64 B: host tier
-    (1024, True, []),        # 32 KiB rank-major: the memoized plan
-    (1024, False, []),       # the routed slow path's plan(x)
-], ids=["host", "plan", "slow"])
-def test_allreduce_span_tree(world, elems, fast, children):
+_HOST = ["coll.host_fetch", "coll.host_put"]
+
+
+@pytest.mark.parametrize("elems,fast,children,lane", [
+    (2, True, _HOST, False),    # 64 B: host tier, the call that routes it
+    (1024, True, [], False),    # 32 KiB rank-major: the routed plan
+    (1024, False, [], False),   # the routed slow path's plan(x)
+    (3, True, _HOST, True),     # the repeat call, served by the lane
+    (1025, True, [], True),
+], ids=["host", "plan", "slow", "lane-host", "lane-plan"])
+def test_allreduce_span_tree(world, elems, fast, children, lane):
     import jax.numpy as jnp
 
     comp, fn = world._coll["allreduce"]
@@ -204,8 +209,13 @@ def test_allreduce_span_tree(world, elems, fast, children):
     try:
         x = world.put_rank_major(
             jnp.ones((world.size, elems), jnp.float32))
+        if lane:
+            world.allreduce(x, op="sum")
+        hits = SPC.snapshot().get("coll_allreduce_lane_hits", 0)
         recorder.configure(256)
         world.allreduce(x, op="sum")
+        assert SPC.snapshot().get("coll_allreduce_lane_hits", 0) \
+            == hits + lane
     finally:
         config.set("coll_tuned_fast_dispatch_cache", saved)
     recs = _records()

@@ -26,7 +26,7 @@ import pytest
 import ompi_tpu as mt
 from ompi_tpu import telemetry
 from ompi_tpu.analysis.lint import Linter
-from ompi_tpu.core import config, counters
+from ompi_tpu.core import config, counters, dispatch_epoch
 from ompi_tpu.core.counters import SPC
 from ompi_tpu.coll import sched, tuned
 from ompi_tpu.coll.sched import autotune, ir, retune, slo
@@ -452,9 +452,9 @@ def test_slo_targets_and_violation_minutes():
     old = config.get("coll_slo_p50_us")
     try:
         assert slo.target_for("7") == 0.0  # no SLO configured
-        g0 = slo.generation()
+        g0 = dispatch_epoch.value
         slo.set_target("7", 50.0)
-        assert slo.generation() > g0  # memoized plans re-consult
+        assert dispatch_epoch.value > g0  # memoized plans re-consult
         assert slo.target_for("7") == 50.0
         config.set("coll_slo_p50_us", 25.0)
         assert slo.target_for(None) == 25.0
