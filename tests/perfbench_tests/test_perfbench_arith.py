@@ -75,6 +75,7 @@ def test_end_to_end_readers_on_recorded_calls():
     bus = 1.5 * ((1 << 24) + (1 << 30))
     assert read("busbw_GBps") == pytest.approx(bus / (0.001 + 0.02) / 1e9)
     assert read("lat_p50_us") == pytest.approx(300.0)
+    assert read("lat_p50_us.local") == read("lat_p50_us")
     assert read("lat_p99_us") == pytest.approx(400.0)
     assert read("dispatch_us.lat") == pytest.approx(100.0)
     assert read("setup_s") == 12.5

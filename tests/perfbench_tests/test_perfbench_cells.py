@@ -1,13 +1,18 @@
 """Each benchmark cell driven end to end on the CPU mesh at a tiny size,
 past the harness's look for a chip: a sound run is correct, and the
-control (the reference in bfloat16 in the program's place) and every
-fault the cell can have, planted under the timed path, are not."""
+control (the reference one precision below the configuration's type, in
+the program's place) and every fault the cell can have, planted under
+the timed path, are not."""
 
 from __future__ import annotations
 
+import itertools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax import lax
 
 import ompi_tpu
 from ompi_tpu import ops
@@ -26,7 +31,15 @@ TINY = {
         {"name": "reduce", "share": 1.0, "order": "slices",
          "sizes": [65536, 262144], "buffers": 1, "samples": 2,
          "sample_below": 2, "trace_seconds": 0.05}]},
+    "imb_reduce_local_max_i32.1chip": {"groups": [
+        {"name": "lat", "share": 0.5, "order": "interleave",
+         "sizes": [8, 4096], "buffers": 2, "samples": 3,
+         "sample_below": 4, "trace_seconds": 0.05},
+        {"name": "reduce", "share": 0.5, "order": "slices",
+         "sizes": [65536, 262144], "buffers": 1, "samples": 2,
+         "sample_below": 2, "trace_seconds": 0.05}]},
 }
+TINY["imb_allreduce_bf16.4chip"] = TINY["imb_allreduce.4chip"]
 SEED = 2**31 + 12345
 
 
@@ -61,6 +74,38 @@ def test_inputs_come_from_the_seed_in_one_call():
         inputs.int_valued(inputs.base_key(1), (4,), 6, "float32", sharding)
 
 
+def test_bf16_inputs_sum_exactly_in_every_order_and_e4m3_rounds_them():
+    sharding = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    x = inputs.int_valued(inputs.base_key(SEED), (4, 4096), 64, "bfloat16",
+                          sharding)
+    assert x.dtype == jnp.bfloat16
+    exact = np.asarray(x, np.float32).sum(axis=0)
+    for a, b, c, d in itertools.permutations(range(4)):
+        chain = ((x[a] + x[b]) + x[c]) + x[d]
+        tree = (x[a] + x[b]) + (x[c] + x[d])
+        assert (np.asarray(chain, np.float32) == exact).all()
+        assert (np.asarray(tree, np.float32) == exact).all()
+    every = jnp.arange(-64, 64, dtype=jnp.bfloat16)
+    e4m3 = lax.reduce_precision(every, exponent_bits=4, mantissa_bits=3)
+    assert int(jnp.sum(e4m3 != every)) == 64
+
+
+def test_reduce_local_max_reference():
+    ref = harness.load_module("references", "reduce_local_max")
+    sharding = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    buf = tuple(inputs.int_valued(inputs.base_key(SEED + i), (1024,),
+                                  1 << 20, "int32", sharding)
+                for i in range(2))
+    assert ref.gap(buf, ops.reduce_local("max", *buf)) == 0.0
+    assert ref.gap(buf, ref.control(buf)) > 0.0
+    assert ref.gap(buf, jnp.maximum(*buf).astype(jnp.float32)) == \
+        float("inf")
+    # off by 2**31: the int32 difference wraps to its minimum, whose int32
+    # absolute value is negative; the gap still reads it
+    wrapped = jnp.maximum(*buf).at[0].add(jnp.int32(-2**31))
+    assert ref.gap(buf, wrapped) == 2.0**31
+
+
 @pytest.mark.parametrize("name", sorted(TINY))
 def test_sound_run_is_correct(name, tmp_path):
     r = _run(name, tmp_path)
@@ -83,6 +128,13 @@ def test_allreduce_metrics_and_tier_line(tmp_path):
         >= r["metrics"]["lat_p50_us"]["value"] > 0
     tiers = [ln for ln in r["lines"] if ln.startswith("tier selection")]
     assert tiers and "coll_allreduce_algo_host" in tiers[0]
+
+
+def test_reduce_local_max_metrics(tmp_path):
+    r = _run("imb_reduce_local_max_i32.1chip", tmp_path)
+    assert set(r["metrics"]) == {"lat_p50_us.local", "reduce_GBps",
+                                 "setup_s"}
+    assert r["metrics"]["lat_p50_us.local"]["value"] > 0
 
 
 def test_traced_run_reports_per_layer_metrics(tmp_path):
@@ -144,7 +196,7 @@ def _half_the_batch(op, inbuf, inout):
 
 
 def _local_answer_altered(op, inbuf, inout):
-    return _real_reduce_local(op, inbuf, inout).at[-1].add(1.0)
+    return _real_reduce_local(op, inbuf, inout).at[-1].add(1)
 
 
 @pytest.mark.parametrize("fault", [_state_unchanged, _half_the_batch,
@@ -153,6 +205,51 @@ def test_reduce_local_fault_is_caught(fault, tmp_path, monkeypatch):
     monkeypatch.setattr(ops, "reduce_local", fault)
     r = _run("imb_reduce_local.1chip", tmp_path)
     assert not r["correct"]
+
+
+@pytest.mark.parametrize("fault", [_exchange_left_out, _half_the_ranks,
+                                   _answer_altered])
+def test_bf16_allreduce_fault_is_caught(fault, tmp_path, monkeypatch):
+    ompi_tpu.init()
+    monkeypatch.setattr(Communicator, "allreduce", fault)
+    r = _run("imb_allreduce_bf16.4chip", tmp_path)
+    assert not r["correct"]
+
+
+def test_bf16_allreduce_takes_the_host_tier_at_8_bytes(tmp_path):
+    r = _run("imb_allreduce_bf16.4chip", tmp_path)
+    assert r["correct"], r["checks"]
+    tiers = [ln for ln in r["lines"] if ln.startswith("tier selection")]
+    assert tiers and "coll_allreduce_algo_host" in tiers[0]
+
+
+@pytest.mark.parametrize("fault", [_state_unchanged, _half_the_batch,
+                                   _local_answer_altered])
+def test_reduce_local_max_fault_is_caught(fault, tmp_path, monkeypatch):
+    monkeypatch.setattr(ops, "reduce_local", fault)
+    r = _run("imb_reduce_local_max_i32.1chip", tmp_path)
+    assert not r["correct"]
+
+
+def test_reduce_local_max_state_unchanged_in_the_window_is_caught(
+        tmp_path, monkeypatch):
+    # MAX saturates a chained inoutbuf in the warm-up, after which the
+    # unchanged state is the right answer: the cell's entry is unchained
+    name = "imb_reduce_local_max_i32.1chip"
+    warm_up = 2 * sum(len(g["sizes"]) * g["buffers"]
+                      for g in TINY[name]["groups"])
+    calls = itertools.count()
+
+    def planted(op, inbuf, inout):
+        if next(calls) < warm_up:
+            return _real_reduce_local(op, inbuf, inout)
+        return inout
+
+    monkeypatch.setattr(ops, "reduce_local", planted)
+    r = _run(name, tmp_path)
+    assert not r["correct"]
+    assert all(c["value"] > 0 for n, c in r["checks"].items()
+               if n.startswith("gap."))
 
 
 def test_raising_call_counts_as_failed(tmp_path):

@@ -127,7 +127,8 @@ REDUCE = {"name": "reduce", "share": 1.0, "order": "slices",
 
 @pytest.mark.parametrize("name,group,metrics", [
     ("imb_allreduce.4chip", LAT, ["route_us.lat", "launch_us.lat"]),
-    ("imb_reduce_local.1chip", REDUCE, ["op_launch_us.reduce"])])
+    ("imb_reduce_local.1chip", REDUCE, ["op_launch_us.reduce"]),
+    ("imb_reduce_local_max_i32.1chip", LAT, ["op_launch_us.lat"])])
 def test_traced_run_reports_the_span_metrics(name, group, metrics,
                                              tmp_path):
     cell = harness.load_cell(harness.load_bench(), name)
@@ -152,7 +153,8 @@ def test_readers_find_nothing_without_spans():
                                      "reduce": {"calls": calls}},
                              peaks=None)
     recorder.configure()  # an empty ring: no span lies in those calls
-    for metric in ("route_us.lat", "launch_us.lat", "op_launch_us.reduce"):
+    for metric in ("route_us.lat", "launch_us.lat", "op_launch_us.reduce",
+                   "op_launch_us.lat"):
         mod = harness.load_module("metrics", metric)
         assert mod.read(empty) is None and mod.read(traced) is None
 
