@@ -28,18 +28,30 @@ Two entry points mirror the two calling contexts:
     ``coll.tuned.allreduce_by_decision``.
   * :func:`allreduce_pytree` — host-side, rank-major buffers through
     the comm vtable (``comm.allreduce`` per bucket), with optional
-    error feedback.
+    error feedback.  It plans from the leaves' shapes and dtypes only,
+    and keeps the plan with its jitted pack and unpack programs
+    (``_pack_buckets`` / ``_unpack_buckets``, outputs on the
+    communicator's rank sharding) per communicator and structure: a
+    repeat step costs one dict lookup before the pack launch, and each
+    bucket takes the communicator's allreduce lane.  Spans:
+    ``coll.allreduce_pytree`` over the call, ``coll.pack`` and
+    ``coll.unpack`` around the two launches, the buckets'
+    ``coll.allreduce`` spans under the root.
 """
 
 from __future__ import annotations
 
-from typing import Any, NamedTuple, Optional
+import math
+import weakref
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core import config
 from ..core.counters import SPC
+from ..trace.span import Span
 
 _bucket_bytes_var = config.register(
     "parallel", "dp", "bucket_bytes",
@@ -56,6 +68,11 @@ SPC.counter(
     "parallel_dp_bucket_leaves",
     "gradient leaves coalesced into buckets",
 )
+SPC.counter(
+    "parallel_dp_plan_builds",
+    "host-side bucket plans built (a pytree structure seen for the "
+    "first time on a communicator)",
+)
 
 
 class Bucket(NamedTuple):
@@ -69,17 +86,17 @@ class Bucket(NamedTuple):
     pieces: tuple
 
 
-def plan_buckets(tree: Any, bucket_bytes: Optional[int] = None
-                 ) -> list[Bucket]:
-    """Deterministic bucket plan for a pytree (shapes only, no data).
-    The plan length IS the collective-dispatch count of a fused
-    allreduce of ``tree``."""
-    if bucket_bytes is None:
-        bucket_bytes = _bucket_bytes_var.value
-    leaves = jax.tree.leaves(tree)
+def _spec(leaf) -> tuple:
+    """(shape, canonical dtype) of a leaf, read without touching its
+    data."""
+    if not hasattr(leaf, "dtype"):
+        leaf = jnp.asarray(leaf)  # a Python scalar
+    return tuple(np.shape(leaf)), jax.dtypes.canonicalize_dtype(leaf.dtype)
+
+
+def _plan(specs: list, bucket_bytes: int) -> list[Bucket]:
     groups: dict = {}
-    for i, leaf in enumerate(leaves):
-        dt = jnp.asarray(leaf).dtype
+    for i, (_, dt) in enumerate(specs):
         groups.setdefault(str(dt), (dt, []))[1].append(i)
     plans: list[Bucket] = []
     for _, (dt, ids) in sorted(groups.items()):
@@ -88,7 +105,7 @@ def plan_buckets(tree: Any, bucket_bytes: Optional[int] = None
         pieces: list = []
         elems = 0
         for i in ids:
-            size = jnp.asarray(leaves[i]).size
+            size = math.prod(specs[i][0])
             lo = 0
             while lo < size or size == 0:
                 take = min(size - lo, cap - elems) if fused else size
@@ -109,67 +126,36 @@ def plan_buckets(tree: Any, bucket_bytes: Optional[int] = None
     return plans
 
 
+def plan_buckets(tree: Any, bucket_bytes: Optional[int] = None
+                 ) -> list[Bucket]:
+    """Deterministic bucket plan for a pytree (shapes only, no data).
+    The plan length IS the collective-dispatch count of a fused
+    allreduce of ``tree``."""
+    if bucket_bytes is None:
+        bucket_bytes = _bucket_bytes_var.value
+    return _plan([_spec(leaf) for leaf in jax.tree.leaves(tree)],
+                 bucket_bytes)
+
+
 def _gather_bucket(leaves: list, bucket: Bucket, flat_axis: int):
     parts = [
-        jnp.asarray(leaves[i]).reshape(
-            leaves[i].shape[:flat_axis] + (-1,))[..., lo:hi]
+        leaves[i].reshape(leaves[i].shape[:flat_axis] + (-1,))[..., lo:hi]
         for i, lo, hi in bucket.pieces
     ]
     return jnp.concatenate(parts, axis=-1)
 
 
-def _scatter_bucket(out_flat: dict, reduced, bucket: Bucket) -> None:
-    off = 0
-    for i, lo, hi in bucket.pieces:
-        out_flat.setdefault(i, []).append(reduced[..., off:off + (hi - lo)])
-        off += hi - lo
-
-
-def _reassemble(leaves: list, out_flat: dict, flat_axis: int) -> list:
-    out = []
-    for i, leaf in enumerate(leaves):
-        arr = jnp.asarray(leaf)
-        if i not in out_flat:          # zero-size leaf: nothing moved
-            out.append(arr)
-            continue
-        flat = jnp.concatenate(out_flat[i], axis=-1)
-        out.append(flat.reshape(arr.shape))
-    return out
-
-
-#: Jitted gather/reassemble programs keyed by bucket plan: the host-side
-#: path re-uses the same plan every step (bucketing is deterministic), so
-#: the per-call cost of slicing N leaves into buckets and back is one
-#: executable launch each instead of ~2N separate jnp dispatches.
-_PLAN_JIT_CACHE: dict = {}
-
-
-def _plan_jit(plan: list, flat_axis: int, tag: str, make):
-    key = (tag, flat_axis,
-           tuple((str(b.dtype), b.elems, b.pieces) for b in plan))
-    fn = _PLAN_JIT_CACHE.get(key)
-    if fn is None:
-        fn = _PLAN_JIT_CACHE[key] = jax.jit(make())
-    return fn
-
-
-def _gather_fn(plan: list, flat_axis: int):
-    def make():
-        def gather(leaves):
-            return [_gather_bucket(leaves, b, flat_axis) for b in plan]
-        return gather
-    return _plan_jit(plan, flat_axis, "gather", make)
-
-
-def _reassemble_fn(plan: list, flat_axis: int):
-    def make():
-        def reassemble(leaves, reduced):
-            out_flat: dict = {}
-            for b, r in zip(plan, reduced):
-                _scatter_bucket(out_flat, r, b)
-            return _reassemble(leaves, out_flat, flat_axis)
-        return reassemble
-    return _plan_jit(plan, flat_axis, "reassemble", make)
+def _split_buckets(plan: list, reduced: list, shapes: list) -> list:
+    """The leaves back from their buckets: each leaf's pieces joined in
+    order and given ``shapes[i]`` (leading axes included)."""
+    parts: dict = {}
+    for bucket, r in zip(plan, reduced):
+        off = 0
+        for i, lo, hi in bucket.pieces:
+            parts.setdefault(i, []).append(r[..., off:off + (hi - lo)])
+            off += hi - lo
+    return [jnp.concatenate(parts[i], axis=-1).reshape(shape)
+            for i, shape in enumerate(shapes)]
 
 
 def allreduce_tree(tree: Any, axis_name: str, op: Any = "sum",
@@ -186,17 +172,56 @@ def allreduce_tree(tree: Any, axis_name: str, op: Any = "sum",
     leaves, treedef = jax.tree.flatten(tree)
     if not leaves:
         return tree
+    leaves = [jnp.asarray(leaf) for leaf in leaves]
     plan = plan_buckets(leaves, bucket_bytes)
     SPC.record("parallel_dp_bucket_leaves", len(leaves))
-    out_flat: dict = {}
+    reduced = []
     for bucket in plan:
         payload = _gather_bucket(leaves, bucket, 0)
-        reduced = tuned.allreduce_by_decision(
-            payload, axis_name, op, allow_quant=allow_quant)
+        reduced.append(tuned.allreduce_by_decision(
+            payload, axis_name, op, allow_quant=allow_quant))
         SPC.record("parallel_dp_bucket_dispatches")
-        _scatter_bucket(out_flat, reduced, bucket)
     return jax.tree.unflatten(
-        treedef, _reassemble(leaves, out_flat, 0))
+        treedef, _split_buckets(plan, reduced, [l.shape for l in leaves]))
+
+
+class _Structure(NamedTuple):
+    """What a host-side sync of one pytree structure needs, built once:
+    the jitted pack and unpack programs of its plan, each with outputs
+    pinned to the communicator's rank sharding."""
+    pack: Callable
+    unpack: Callable
+
+
+#: comm -> {(treedef, leaf shapes and dtypes, bucket_bytes): _Structure}.
+#: A DDP step syncs one structure every step: after the first, a call
+#: costs one dict lookup before the pack launch.
+_STRUCTURES: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+
+
+def _build(comm, specs: tuple, bucket_bytes: int) -> _Structure:
+    size = comm.size
+    for shape, _ in specs:
+        if len(shape) < 1 or shape[0] != size:
+            raise ValueError(
+                f"allreduce_pytree needs rank-major (size, ...) leaves,"
+                f" got shape {shape}"
+            )
+    # Plan over the per-rank payload (axis 0 is the rank axis).
+    plan = _plan([(shape[1:], dt) for shape, dt in specs], bucket_bytes)
+    shapes = [shape for shape, _ in specs]
+    sharding = comm.rank_sharding()
+
+    def _pack_buckets(leaves):      # (size, elems) per bucket
+        return [_gather_bucket(leaves, b, 1) for b in plan]
+
+    def _unpack_buckets(reduced):
+        return _split_buckets(plan, reduced, shapes)
+
+    SPC.record("parallel_dp_plan_builds")
+    return _Structure(
+        jax.jit(_pack_buckets, out_shardings=[sharding] * len(plan)),
+        jax.jit(_unpack_buckets, out_shardings=[sharding] * len(shapes)))
 
 
 def allreduce_pytree(comm, tree: Any, op: Any = "sum",
@@ -205,35 +230,44 @@ def allreduce_pytree(comm, tree: Any, op: Any = "sum",
     """Host-side fused allreduce of a pytree of rank-major ``(size,
     ...)`` buffers through the comm VTABLE: one ``comm.allreduce`` per
     bucket, so component selection (tuned/hier/pallas) and the quant
-    tier run per bucket.  ``error_feedback`` is an optional dict used
-    as a residual bank: one :class:`ompi_tpu.coll.quant.ErrorFeedback`
-    per bucket index, created on first use and carried across calls
-    (aligned because bucketing is deterministic — pass the same dict
-    every step)."""
-    leaves, treedef = jax.tree.flatten(tree)
-    if not leaves:
-        return tree
-    size = comm.size
-    for leaf in leaves:
-        arr = jnp.asarray(leaf)
-        if arr.ndim < 1 or arr.shape[0] != size:
-            raise ValueError(
-                f"allreduce_pytree needs rank-major (size, ...) leaves,"
-                f" got shape {arr.shape}"
-            )
-    # Plan over the per-rank payload (axis 0 is the rank axis).
-    per_rank = [jnp.asarray(l)[0] for l in leaves]
-    plan = plan_buckets(per_rank, bucket_bytes)
-    SPC.record("parallel_dp_bucket_leaves", len(leaves))
-    payloads = _gather_fn(plan, 1)(leaves)      # (size, elems) each
-    reduced = []
-    for bi, payload in enumerate(payloads):
-        if error_feedback is not None:
-            from ..coll.quant import ErrorFeedback
+    tier run per bucket.  The plan is read from the leaves' shapes and
+    dtypes, never their data, and kept per communicator and structure
+    with its pack and unpack programs.  ``error_feedback`` is an
+    optional dict used as a residual bank: one
+    :class:`ompi_tpu.coll.quant.ErrorFeedback` per bucket index,
+    created on first use and carried across calls (aligned because
+    bucketing is deterministic — pass the same dict every step)."""
+    with Span("coll.allreduce_pytree", "coll"):
+        leaves, treedef = jax.tree.flatten(tree)
+        if not leaves:
+            return tree
+        if bucket_bytes is None:
+            bucket_bytes = _bucket_bytes_var.value
+        try:
+            specs = tuple((leaf.shape, leaf.dtype) for leaf in leaves)
+        except AttributeError:
+            specs = tuple(_spec(leaf) for leaf in leaves)
+        key = (treedef, specs, bucket_bytes)
+        memo = _STRUCTURES.setdefault(comm, {})
+        st = memo.get(key)
+        if st is None:
+            st = memo[key] = _build(
+                comm, tuple(_spec(leaf) for leaf in leaves), bucket_bytes)
+        SPC.record("parallel_dp_bucket_leaves", len(leaves))
+        with Span("coll.pack", "coll"):
+            payloads = st.pack(leaves)
+        reduced = []
+        for bi in range(len(payloads)):
+            # drop each packed bucket once it is handed on, so the
+            # packed copy of the tree is not all resident at the unpack
+            payload, payloads[bi] = payloads[bi], None
+            if error_feedback is not None:
+                from ..coll.quant import ErrorFeedback
 
-            ef = error_feedback.setdefault(bi, ErrorFeedback())
-            payload = ef.compensate(payload)
-        reduced.append(comm.allreduce(payload, op))
-        SPC.record("parallel_dp_bucket_dispatches")
-    return jax.tree.unflatten(
-        treedef, _reassemble_fn(plan, 1)(leaves, reduced))
+                ef = error_feedback.setdefault(bi, ErrorFeedback())
+                payload = ef.compensate(payload)
+            reduced.append(comm.allreduce(payload, op))
+            SPC.record("parallel_dp_bucket_dispatches")
+        with Span("coll.unpack", "coll"):
+            out = st.unpack(reduced)
+        return jax.tree.unflatten(treedef, out)

@@ -218,3 +218,114 @@ def test_dp_module_routes_through_bucketer():
         np.testing.assert_allclose(
             np.asarray(out[k][0]), np.asarray(v).sum(0),
             rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# host context: planning from shapes, one memo per structure
+# ---------------------------------------------------------------------------
+
+def _sub_comm(n=4):
+    from ompi_tpu.group import Group
+
+    return mt.world().create(Group(list(range(n))))
+
+
+def _rank_major_list(comm, shapes, seed=0):
+    rng = np.random.default_rng(seed)
+    return [comm.put_rank_major(rng.integers(-64, 64, (comm.size,) + s)
+                                .astype(np.float32)) for s in shapes]
+
+
+# a small list of the DDP cell's kinds of leaves: norm vectors, low-rank
+# projections, same-shape expert matrices; at a 2 KiB cap leaves span
+# buckets and the last bucket is short
+_SHAPES = [(40, 16), (16,), (24, 8), (8,), (12, 16), (16, 12), (12, 16),
+           (16, 12), (64, 16), (16,)]
+
+
+def _counts():
+    snap = SPC.snapshot()
+    return {k: snap.get(k, 0) for k in (
+        "parallel_dp_plan_builds", "parallel_dp_bucket_dispatches",
+        "coll_allreduce_lane_hits")}
+
+
+def test_allreduce_pytree_list_is_exact_leaf_by_leaf():
+    comm = _sub_comm()
+    grads = _rank_major_list(comm, _SHAPES)
+    plan = bucketer.plan_buckets([g[0] for g in grads], 2048)
+    assert any(lo > 0 for b in plan for _, lo, _ in b.pieces)
+    out = bucketer.allreduce_pytree(comm, grads, "sum", bucket_bytes=2048)
+    assert isinstance(out, list) and len(out) == len(grads)
+    for g, o in zip(grads, out):
+        assert o.shape == g.shape and o.dtype == g.dtype
+        assert o.sharding.is_equivalent_to(comm.rank_sharding(), o.ndim)
+        want = np.broadcast_to(np.asarray(g).sum(0), g.shape)
+        np.testing.assert_array_equal(np.asarray(o), want)
+
+
+def test_repeat_sync_builds_no_plan_and_takes_the_lane():
+    comm = _sub_comm()
+    grads = _rank_major_list(comm, _SHAPES, seed=1)
+    nb = len(bucketer.plan_buckets([g[0] for g in grads], 2048))
+    c0 = _counts()
+    bucketer.allreduce_pytree(comm, grads, "sum", bucket_bytes=2048)
+    c1 = _counts()
+    assert c1["parallel_dp_plan_builds"] == c0["parallel_dp_plan_builds"] + 1
+    assert c1["parallel_dp_bucket_dispatches"] \
+        == c0["parallel_dp_bucket_dispatches"] + nb
+    out = bucketer.allreduce_pytree(comm, grads, "sum", bucket_bytes=2048)
+    c2 = _counts()
+    assert c2["parallel_dp_plan_builds"] == c1["parallel_dp_plan_builds"]
+    assert c2["parallel_dp_bucket_dispatches"] \
+        == c1["parallel_dp_bucket_dispatches"] + nb
+    # every packed bucket lies on the rank sharding: each takes the lane
+    assert c2["coll_allreduce_lane_hits"] \
+        == c1["coll_allreduce_lane_hits"] + nb
+    np.testing.assert_array_equal(np.asarray(out[0]), np.broadcast_to(
+        np.asarray(grads[0]).sum(0), grads[0].shape))
+    # another cap, or another structure, is another plan
+    bucketer.allreduce_pytree(comm, grads, "sum", bucket_bytes=4096)
+    bucketer.allreduce_pytree(comm, grads[1:], "sum", bucket_bytes=2048)
+    assert _counts()["parallel_dp_plan_builds"] \
+        == c2["parallel_dp_plan_builds"] + 2
+
+
+def test_allreduce_pytree_plans_without_reading_leaves(monkeypatch):
+    """The plan comes from shapes and dtypes: no leaf is indexed or
+    copied to plan, on a first call or a repeat."""
+    comm = _sub_comm()
+    grads = _rank_major_list(comm, _SHAPES, seed=2)
+
+    def no_index(self, idx):
+        raise AssertionError("a leaf was indexed to plan")
+
+    monkeypatch.setattr(type(grads[0]), "__getitem__", no_index)
+    for _ in range(2):
+        out = bucketer.allreduce_pytree(comm, grads, "sum",
+                                        bucket_bytes=2048)
+    monkeypatch.undo()
+    np.testing.assert_array_equal(np.asarray(out[3]), np.broadcast_to(
+        np.asarray(grads[3]).sum(0), grads[3].shape))
+
+
+def test_allreduce_pytree_spans_nest_the_buckets():
+    from ompi_tpu.trace import recorder
+
+    comm = _sub_comm()
+    grads = _rank_major_list(comm, _SHAPES, seed=3)
+    nb = len(bucketer.plan_buckets([g[0] for g in grads], 2048))
+    bucketer.allreduce_pytree(comm, grads, "sum", bucket_bytes=2048)
+    recorder.configure()
+    try:
+        bucketer.allreduce_pytree(comm, grads, "sum", bucket_bytes=2048)
+        begins = [r for r in recorder.get().records() if r[2] == "B"]
+    finally:
+        recorder.configure()
+    root = [r for r in begins if r[3] == "coll.allreduce_pytree"]
+    assert len(root) == 1
+    under = {}
+    for r in begins:
+        if r[6] == root[0][5]:
+            under[r[3]] = under.get(r[3], 0) + 1
+    assert under == {"coll.pack": 1, "coll.allreduce": nb, "coll.unpack": 1}

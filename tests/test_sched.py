@@ -441,13 +441,18 @@ def test_sched_counters_reach_monitoring_dump(clean_cache):
     from ompi_tpu.trace import recorder
 
     rec = recorder.configure(1024)
-    fp = autotune.fingerprint()
-    scache.CACHE.put(
-        scache.cache_key("allreduce", 1024, 8, "float32", fp),
-        "ring", source="test")
-    op = op_lookup("sum")
-    tuned.decide_allreduce(op, 1024, 8, "float32")
-    tuned.decide_allreduce(op, 64 << 20, 8, "float32")
+    try:
+        fp = autotune.fingerprint()
+        scache.CACHE.put(
+            scache.cache_key("allreduce", 1024, 8, "float32", fp),
+            "ring", source="test")
+        op = op_lookup("sum")
+        tuned.decide_allreduce(op, 1024, 8, "float32")
+        tuned.decide_allreduce(op, 64 << 20, 8, "float32")
+    finally:
+        # the process ring back at its default size: a later test in
+        # this process may read a traced slice longer than 1024 records
+        recorder.configure()
     snap = SPC.snapshot()
     assert snap.get("sched_cache_hits", 0) >= 1
     assert snap.get("sched_cache_misses", 0) >= 1
